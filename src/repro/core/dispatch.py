@@ -31,8 +31,9 @@ per-op fallback order
 
     pallas -> interpret -> reference
 
-walked one rung at a time by :class:`DegradationLadder`: on a kernel
-exception or a NaN/Inf logit-guard trip the engine demotes the implicated op
+walked one rung at a time by :class:`DegradationLadder`: on a ``KernelFault``
+(injected by a fault plan, or a NaN/Inf logit-guard trip) the engine demotes
+the implicated op
 (``"decode_attention"`` or ``"pim_gemv"`` — independently, via
 ``cfg.gemv_backend``), warns ONCE per transition, counts the event in its
 health counters, and retries the step. Cross-backend token identity is a
@@ -148,8 +149,9 @@ class DegradationLadder:
         return True
 
     def degrade_any(self, reason: str = "") -> bool:
-        """Unattributed failure: demote the first op that still has a rung
-        below it (attention first — it dominates the decode step)."""
+        """The faulting op is at its floor: demote the first op that still
+        has a rung below it (attention first — it dominates the decode
+        step)."""
         return any(self.degrade(op, reason) for op in LADDER_OPS)
 
     def record_nan(self, op: str = "decode_attention") -> None:

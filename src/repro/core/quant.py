@@ -53,6 +53,15 @@ def quantize_weight(w: jax.Array) -> QuantizedLinear:
     return QuantizedLinear(w_q=wq, w_scale=ws)
 
 
+@jax.jit
+def _quantize_leaf(w: jax.Array) -> QuantizedLinear:
+    """One (K, N) or layer-stacked (nL, K, N) leaf, as ONE program: run
+    eagerly, a stacked leaf materializes several float32 copies of itself,
+    which at published widths outgrows the device memory left beside the
+    weights."""
+    return quantize_weight(w) if w.ndim == 2 else jax.vmap(quantize_weight)(w)
+
+
 def quantize_params_tree(params, path_suffixes=DECODE_LINEAR_SUFFIXES,
                          exclude=None):
     """Quantize every matching weight leaf of a param tree to int8.
@@ -71,8 +80,7 @@ def quantize_params_tree(params, path_suffixes=DECODE_LINEAR_SUFFIXES,
         if exclude is not None and exclude(key):
             continue
         if leaf.ndim in (2, 3) and any(key.endswith(f"['{s}']") for s in path_suffixes):
-            out[key] = (quantize_weight(leaf) if leaf.ndim == 2
-                        else jax.vmap(quantize_weight)(leaf))
+            out[key] = _quantize_leaf(leaf)
     return out
 
 

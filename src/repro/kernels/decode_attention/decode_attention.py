@@ -289,8 +289,8 @@ def _split_kernel(start_ref, end_ref, table_ref, q_ref, k_ref, v_ref,
         # UNNORMALIZED partials: stage 2 owns the division. Dead splits pass
         # their init state through — the merge identity.
         acc_out_ref[0, 0, 0] = acc_ref[...]
-        m_out_ref[0, 0, 0] = m_ref[...]
-        l_out_ref[0, 0, 0] = l_ref[...]
+        m_out_ref[0, 0, 0, 0] = m_ref[...]
+        l_out_ref[0, 0, 0, 0] = l_ref[...]
 
 
 def _clamp_split(blk, start, end, bsz, s_lo, s_hi):
@@ -359,13 +359,15 @@ def decode_attention_paged_split(
                          lambda i, j, si, jj, sr, er, tr:
                          (_page(si * bps + jj, si, sr, er, tr, i), j, 0, 0)),
         ],
+        # m / l carry a unit second-minor axis: a (.., n_splits, g) block of
+        # one split would tile n_splits by 1, which Mosaic refuses
         out_specs=[
             pl.BlockSpec((1, 1, 1, g, hd),
                          lambda i, j, si, jj, sr, er, tr: (i, j, si, 0, 0)),
-            pl.BlockSpec((1, 1, 1, g),
-                         lambda i, j, si, jj, sr, er, tr: (i, j, si, 0)),
-            pl.BlockSpec((1, 1, 1, g),
-                         lambda i, j, si, jj, sr, er, tr: (i, j, si, 0)),
+            pl.BlockSpec((1, 1, 1, 1, g),
+                         lambda i, j, si, jj, sr, er, tr: (i, j, si, 0, 0)),
+            pl.BlockSpec((1, 1, 1, 1, g),
+                         lambda i, j, si, jj, sr, er, tr: (i, j, si, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((g,), jnp.float32),
@@ -380,9 +382,9 @@ def decode_attention_paged_split(
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, hkv, n_splits, g, hd), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, n_splits, g), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, n_splits, g), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, n_splits, 1, g), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, n_splits, 1, g), jnp.float32),
         ],
         interpret=interpret,
     )(start_b, end_b, jnp.asarray(block_table, jnp.int32), q, k_pages, v_pages)
-    return merge_splits(acc, m, l)
+    return merge_splits(acc, m[:, :, :, 0], l[:, :, :, 0])

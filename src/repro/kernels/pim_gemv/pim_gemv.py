@@ -36,19 +36,23 @@ def _gemv_kernel(x_ref, w_ref, wscale_ref, xscale_ref, out_ref, acc_ref, *, n_k:
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # int8 x int8 -> int32 MAC block (the CU datapath)
+    # int8 x int8 -> int32 MAC block (the CU datapath). Integer MACs are
+    # exact at any precision; pinning DEFAULT keeps an ambient
+    # default_matmul_precision("highest") (the f32 reference recipe) from
+    # asking Mosaic for an fp32 contraction of int8 operands, which it refuses.
     x = x_ref[...]  # (B, BK) int8
     w = w_ref[...]  # (BN, BK) int8
     acc_ref[...] += jax.lax.dot_general(
         x, w,
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.DEFAULT,
         preferred_element_type=jnp.int32,
     )
 
     @pl.when(k == n_k - 1)
     def _epilogue():
         acc = acc_ref[...].astype(jnp.float32)
-        out_ref[...] = acc * xscale_ref[...][:, None] * wscale_ref[...][None, :]
+        out_ref[...] = acc * xscale_ref[...] * wscale_ref[...]  # (B,1), (1,BN)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_k", "interpret"))
@@ -77,11 +81,13 @@ def pim_gemv(
         in_specs=[
             pl.BlockSpec((b, bk), lambda i, j: (0, j)),      # x: resident per k
             pl.BlockSpec((bn, bk), lambda i, j: (i, j)),     # w: streamed tiles
-            pl.BlockSpec((bn,), lambda i, j: (i,)),          # w_scale
-            pl.BlockSpec((b,), lambda i, j: (0,)),           # x_scale
+            # scales ride as 2-D rows/columns: Mosaic tiles a 1-D f32 operand
+            # differently from XLA's layout for it and refuses the kernel
+            pl.BlockSpec((1, bn), lambda i, j: (0, i)),      # w_scale (1, N)
+            pl.BlockSpec((b, 1), lambda i, j: (0, 0)),       # x_scale (B, 1)
         ],
         out_specs=pl.BlockSpec((b, bn), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((b, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((b, bn), jnp.int32)],
         interpret=interpret,
-    )(x, w, w_scale, x_scale)
+    )(x, w, w_scale.reshape(1, n), x_scale.reshape(b, 1))

@@ -25,6 +25,7 @@ attainment when deadlines are declared.
 from __future__ import annotations
 
 import argparse
+import sys
 import time
 
 import jax
@@ -32,15 +33,18 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core.pim_modes import Mode, SloAwarePolicy
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.serve import traffic
-from repro.serve.api import GenerationRequest, SamplingParams
+from repro.serve.api import GenerationRequest, RequestState, SamplingParams
 from repro.serve.faults import FaultPlan
 from repro.serve.serving_model import ServingModel
 from repro.serve.spec import SpecConfig
 
 
-def main() -> None:
+def main() -> str | None:
+    """Run the launcher; returns an error message (the process's non-zero
+    exit) when a request FAILED or the ladder degraded without ``--faults``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -106,14 +110,17 @@ def main() -> None:
                          "(equivalent to --mode)")
     args = ap.parse_args()
 
+    cache_dir = enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     if args.quantized_decode:
         cfg = cfg.replace(quantized_decode=True)
     params = M.init_params(jax.random.PRNGKey(0), cfg)
     max_len = args.prompt_len + args.max_new + 8
     sm = ServingModel.prepare(cfg, params, slots=args.slots, max_len=max_len)
+    dev = jax.devices()[0]
     print(f"prepared {cfg.name}: backend={sm.backend} "
-          f"prequantized={sm.prequantized}")
+          f"prequantized={sm.prequantized} device={dev.platform}/"
+          f"{dev.device_kind} x{len(jax.devices())} compile_cache={cache_dir}")
     spec = None
     if args.spec_draft is not None:
         dcfg = get_config(args.spec_draft, smoke=args.smoke)
@@ -213,7 +220,14 @@ def main() -> None:
           f"prefix_pins={occ['prefix_pins']}")
     if args.faults is not None:
         print(f"ladder: {h['ladder']} fault_plan: {h['fault_plan']}")
+    # a fallback outside a chaos run, or a failed request, is a failed run:
+    # it would otherwise exit 0 while serving from a path nobody asked for
+    failed = sum(r.state is RequestState.FAILED for r in results)
+    if failed or (h["degraded"] and args.faults is None):
+        return (f"serve failed: {failed} request(s) FAILED, "
+                f"ladder={h['ladder']}")
+    return None
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -281,9 +281,10 @@ class Engine:
         * lane pressure preempts the lowest-priority RUNNING slot — the
           victim requeues with its emitted tokens and resumes bit-identically
           (re-prefill of prompt + emitted tokens on the same RNG lane);
-        * a kernel exception or NaN/Inf logit trip demotes the implicated op
-          down the dispatch ladder and retries the step; only ladder
-          exhaustion fails the step's in-flight requests (others continue).
+        * a :class:`KernelFault` (injected, or a NaN/Inf logit trip) demotes
+          the implicated op down the dispatch ladder and retries the step;
+          only ladder exhaustion fails the step's in-flight requests (others
+          continue). Any other exception raised by a step propagates.
         """
         self._require(self.serving is not None and self.pool is not None,
                       "Engine not prepared: serving artifact / cache pool "
@@ -651,8 +652,8 @@ class Engine:
                 stream.cache = pool.staging_step_prep(stream.cache, c)
 
             # ---- guarded step execution: compute WITHOUT mutating pool or
-            # stream; on a kernel exception or NaN/Inf trip, demote the
-            # implicated op down the dispatch ladder and retry. Commit only
+            # stream; on a KernelFault (injected or a NaN/Inf trip), demote
+            # the implicated op down the dispatch ladder and retry. Commit only
             # a clean step's outputs — a retried step never double-appends.
             dparams = self.serving.decode_params
             logits = pre_logits = new_cache = new_scache = None
@@ -766,23 +767,22 @@ class Engine:
                             "non-finite logits (NaN/Inf guard trip)")
                     step_ok = True
                     break
-                except EngineStateError:
-                    raise
-                except Exception as e:  # noqa: BLE001 — the ladder IS the handler
-                    # a dead attempt's forks are reinstated NOW — rows and
+                except KernelFault as e:
+                    # Only a KernelFault (injected, or the NaN guard's) is a
+                    # recoverable step fault. Any other exception -- a
+                    # lowering, compile or runtime error of the device --
+                    # propagates: demoting past it would keep serving on a
+                    # path the operator never asked for.
+                    # A dead attempt's forks are reinstated NOW — rows and
                     # refcounts bit-identical to pre-round — so the ladder
-                    # retry (or the failure path below) starts clean
+                    # retry (or the failure path below) starts clean.
                     for fk in forks.values():
                         if fk.live:
                             pool.restore_lane(fk)
                     H["retried_steps"] += 1
-                    if isinstance(e, KernelFault):
-                        ladder.record_fault(e.op)
-                        recovered = (ladder.degrade(e.op, str(e))
-                                     or ladder.degrade_any(str(e)))
-                    else:
-                        recovered = ladder.degrade_any(
-                            f"{type(e).__name__}: {e}")
+                    ladder.record_fault(e.op)
+                    recovered = (ladder.degrade(e.op, str(e))
+                                 or ladder.degrade_any(str(e)))
                     if not recovered:
                         break  # ladder exhausted: the step fails
 

@@ -94,9 +94,11 @@ class EngineStateError(ServingError):
 
 class KernelFault(ServingError):
     """A kernel-level failure attributed to one dispatched op (``op``) —
-    raised by real backends at trace/compile time or injected by a
+    raised by the engine's NaN/Inf logit guard or injected by a
     :class:`~repro.serve.faults.FaultPlan`; the engine answers it by walking
-    that op down the degradation ladder and retrying the step."""
+    that op down the degradation ladder and retrying the step. It is the
+    only step exception the engine recovers from: a lowering, compile or
+    device runtime error propagates out of ``Engine.serve``."""
 
     def __init__(self, op: str, message: str = "", injected: bool = False):
         super().__init__(message or f"kernel fault in {op!r}")

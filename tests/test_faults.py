@@ -20,12 +20,14 @@ the floor for injected kernel faults to be *recoverable* (interpret and
 reference are token-bitwise identical, so baselines stay comparable).
 """
 import dataclasses
+import warnings
 
 import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.configs import get_config
+from repro.core import interleave
 from repro.core.pim_modes import Mode
 from repro.models import model as M
 from repro.pimsim import CDPIM, JETSON, LLAMA_1B, replay_events
@@ -301,6 +303,34 @@ def test_ladder_is_sticky_across_serve_calls(setup, baseline):
     res = eng.serve(_reqs())
     assert eng.ladder.is_degraded()
     assert [r.tokens for r in res] == baseline[Mode.HBCEM]
+
+
+@pytest.mark.parametrize("mode", [Mode.HBCEM, Mode.LBIM])
+def test_non_kernel_fault_propagates_without_demotion(setup, monkeypatch,
+                                                      mode):
+    """Only a KernelFault is recoverable. A device error raised inside a
+    step -- here a Mosaic compile refusal -- leaves serve() at once and moves
+    no ladder rung, so the engine never keeps serving on a fallback path
+    (the Pallas interpreter, the jnp oracle) while reporting success."""
+    cfg, params = setup
+    eng = _engine(cfg, params, mode)
+
+    def refuse(*_a, **_kw):
+        raise jax.errors.JaxRuntimeError(
+            "INVALID_ARGUMENT: Mosaic failed to compile TPU kernel")
+
+    for step in ("decode_only_step", "fused_step", "prefill_chunk_step"):
+        monkeypatch.setattr(interleave, step, refuse)
+    with warnings.catch_warnings():
+        warnings.simplefilter("error", RuntimeWarning)  # a demotion warns
+        with pytest.raises(jax.errors.JaxRuntimeError, match="Mosaic"):
+            eng.serve(_reqs())
+    health = eng.health()
+    assert not health["degraded"]
+    assert health["counters"]["retried_steps"] == 0
+    for op in health["ladder"].values():
+        assert op["backend"] == op["base"] == "interpret"
+        assert op["fallbacks"] == op["kernel_faults"] == 0
 
 
 def test_ladder_exhaustion_fails_participants_not_engine(setup):
