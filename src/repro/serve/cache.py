@@ -75,6 +75,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core import kv_mapping
 from repro.models import model as M
+from repro.serve import trace
 from repro.serve.errors import (EngineStateError, PoolExhausted,
                                 PoolOccupancy)
 
@@ -230,6 +231,7 @@ class CacheState(Protocol):
     """One cache family's slice of the slot pool, behind a uniform protocol."""
 
     spec: StateSpec
+    copied: int   # bytes written by eager updates, until the pool takes them
 
     def insert(self, src_cache: dict, slot: int, src_slot: int) -> None: ...
     def retire(self, slot: int) -> None: ...
@@ -243,15 +245,18 @@ class _LaneState:
     def __init__(self, spec: StateSpec, leaves: dict):
         self.spec = spec
         self.leaves = {k: leaves[k] for k in spec.keys}
+        self.copied = 0   # bytes written by eager lane updates
 
     def insert(self, src_cache: dict, slot: int, src_slot: int) -> None:
         for k in self.spec.keys:
             self.leaves[k] = _copy_lane(self.leaves[k], src_cache[k], slot, src_slot)
+            self.copied += self.leaves[k].nbytes
 
     def retire(self, slot: int) -> None:
         if self.spec.zero_on_retire:
             for k in self.spec.keys:
                 self.leaves[k] = _zero_lane(self.leaves[k], slot)
+                self.copied += self.leaves[k].nbytes
 
     def views(self) -> dict:
         return dict(self.leaves)
@@ -351,6 +356,14 @@ class PagedKVState:
         # live fork rows (identity-keyed): each holds one ref per page and
         # is part of the audit's expected-refcount reconstruction
         self._forks: list[np.ndarray] = []
+        # bytes written by eager updates of the page arrays: each one
+        # materialises both whole arrays, however few pages it touches
+        self.copied = 0
+
+    def _set_pages(self, pages: dict) -> None:
+        """Take the output of an eager update of the page arrays."""
+        self.pages = pages
+        self.copied += pages["k_pages"].nbytes + pages["v_pages"].nbytes
 
     def __len__(self) -> int:
         """Indexed prefix entries (``prefix_report``'s ``stored_blocks``)."""
@@ -462,12 +475,12 @@ class PagedKVState:
                 self.block_tables[slot, wb] = self._alloc_page()
             elif self.refcount[p] > 1:
                 q = self._alloc_page()
-                self.pages = {
+                self._set_pages({
                     "k_pages": self.pages["k_pages"].at[:, q].set(
                         self.pages["k_pages"][:, p]),
                     "v_pages": self.pages["v_pages"].at[:, q].set(
                         self.pages["v_pages"][:, p]),
-                }
+                })
                 self.block_tables[slot, wb] = q
                 self._unref(p)
 
@@ -590,12 +603,12 @@ class PagedKVState:
                 "paged insert from a stream cache with no staging handle")
         if h.fresh:
             idx = np.asarray(sorted(h.fresh), np.int64)
-            self.pages = {
+            self._set_pages({
                 "k_pages": self.pages["k_pages"].at[:, idx].set(
                     src_cache["k_pages"][:, idx]),
                 "v_pages": self.pages["v_pages"].at[:, idx].set(
                     src_cache["v_pages"][:, idx]),
-            }
+            })
         self.block_tables[slot] = h.table
         self.staging = None
 
@@ -616,8 +629,8 @@ class PagedKVState:
             p = self._alloc_page()
             kb, vb = kv_mapping.extract_block(k_lane, v_lane, i,
                                               self.block_size)
-            self.pages = kv_mapping.store_block(
-                self.pages, p, kb.astype(kd), vb.astype(kd))
+            self._set_pages(kv_mapping.store_block(
+                self.pages, p, kb.astype(kd), vb.astype(kd)))
             self.block_tables[slot, i] = p
 
     def retire(self, slot: int) -> None:
@@ -779,6 +792,22 @@ class CachePool:
             self.states = [self._make_state(s, cache) for s in self.specs]
             self._pos = cache["pos"]
         self.slots: list[SlotInfo] = [SlotInfo() for _ in range(self.n_slots)]
+        self._copied = 0
+        self.take_copy_bytes()   # a kept page pool's earlier copies are dropped
+
+    def _set_pos(self, pos: jax.Array) -> None:
+        """Take the output of an eager update of the lanes' fill levels."""
+        self._pos = pos
+        self._copied += pos.nbytes
+
+    def take_copy_bytes(self) -> int:
+        """Bytes written by the pool's eager updates (page arrays, lane
+        leaves, fill levels) since the last call. An update outside a jitted
+        program writes its whole output array, so each counts that array."""
+        n, self._copied = self._copied, 0
+        for st in self.states:
+            n, st.copied = n + st.copied, 0
+        return n
 
     def reset(self) -> None:
         """Fresh lanes, slot table, and per-drain stats; the prefix index
@@ -896,30 +925,32 @@ class CachePool:
         For paged pools the source is either the drained admission stream
         (pages merged, table row transferred) or a contiguous prefill
         (pagified block by block)."""
-        kv = self._kv
-        if kv is not None:
-            try:
-                kv.insert(prefilled, slot, src_slot)
-            except _PagesExhausted as e:
-                raise PoolExhausted(str(e), self.occupancy()) from None
-            src_pos = jnp.reshape(
-                jnp.asarray(prefilled["pos"], jnp.int32), (-1,))
-            src_pos = src_pos[src_slot if src_pos.shape[0] > 1 else 0]
-            self._pos = self._pos.at[slot].set(src_pos)
-            if self.prefix_cache and prompt is not None:
-                kv.harvest(slot, prompt)
-            return
-        for st in self.states:
-            st.insert(prefilled, slot, src_slot)
-        src_pos = normalize_pos(prefilled, lane_count(prefilled))["pos"][src_slot]
-        self._pos = self._pos.at[slot].set(src_pos)
+        with trace.span(trace.POOL_MERGE):
+            kv = self._kv
+            if kv is not None:
+                try:
+                    kv.insert(prefilled, slot, src_slot)
+                except _PagesExhausted as e:
+                    raise PoolExhausted(str(e), self.occupancy()) from None
+                src_pos = jnp.reshape(
+                    jnp.asarray(prefilled["pos"], jnp.int32), (-1,))
+                src_pos = src_pos[src_slot if src_pos.shape[0] > 1 else 0]
+                self._set_pos(self._pos.at[slot].set(src_pos))
+                if self.prefix_cache and prompt is not None:
+                    kv.harvest(slot, prompt)
+                return
+            for st in self.states:
+                st.insert(prefilled, slot, src_slot)
+            src_pos = normalize_pos(prefilled,
+                                    lane_count(prefilled))["pos"][src_slot]
+            self._set_pos(self._pos.at[slot].set(src_pos))
 
     def retire(self, slot: int) -> None:
         """Free lane ``slot``: release its pages (paged), zero spec-derived
         recurrent state, pin fill to 0."""
         for st in self.states:
             st.retire(slot)
-        self._pos = self._pos.at[slot].set(0)
+        self._set_pos(self._pos.at[slot].set(0))
         self.slots[slot] = replace(self.slots[slot], state=FREE)
 
     def views(self, span: int = 1) -> dict:
@@ -927,31 +958,33 @@ class CachePool:
         lane's write blocks for the next ``span`` appends here (host-side
         residency, idempotent — a retried step re-ensures the same pages).
         A speculative verify step passes ``span = k + 1``."""
-        kv = self._kv
-        if kv is not None:
-            pos = np.asarray(self._pos)
-            try:
-                for i, s in enumerate(self.slots):
-                    if s.state == ACTIVE:
-                        kv.ensure_residency(i, int(pos[i]), span)
-            except _PagesExhausted as e:
-                raise PoolExhausted(str(e), self.occupancy()) from None
-        out: dict = {}
-        for st in self.states:
-            out.update(st.views())
-        out["pos"] = self._pos
-        return out
+        with trace.span(trace.POOL_RESIDENCY):
+            kv = self._kv
+            if kv is not None:
+                pos = np.asarray(self._pos)
+                try:
+                    for i, s in enumerate(self.slots):
+                        if s.state == ACTIVE:
+                            kv.ensure_residency(i, int(pos[i]), span)
+                except _PagesExhausted as e:
+                    raise PoolExhausted(str(e), self.occupancy()) from None
+            out: dict = {}
+            for st in self.states:
+                out.update(st.views())
+            out["pos"] = self._pos
+            return out
 
     def commit(self, new_cache: dict) -> None:
         """Absorb a decode step's updated cache. Free lanes decode garbage
         each step; their fill level is pinned back to 0 here so the dummy KV
         write keeps landing at block 0 (the dummy page) and never overflows."""
-        for st in self.states:
-            st.commit(new_cache)
-        free = np.zeros((self.n_slots,), bool)
-        for i in self.free_slots():
-            free[i] = True
-        self._pos = jnp.where(jnp.asarray(free), 0, new_cache["pos"])
+        with trace.span(trace.POOL_COMMIT):
+            for st in self.states:
+                st.commit(new_cache)
+            free = np.zeros((self.n_slots,), bool)
+            for i in self.free_slots():
+                free[i] = True
+            self._set_pos(jnp.where(jnp.asarray(free), 0, new_cache["pos"]))
 
     # -------------------------------------------------------- fork / rollback
 
@@ -979,7 +1012,7 @@ class CachePool:
         if not fork.live:
             raise EngineStateError("restore_lane on a spent fork")
         kv.restore_row(fork.slot, fork.row)
-        self._pos = self._pos.at[fork.slot].set(fork.pos)
+        self._set_pos(self._pos.at[fork.slot].set(fork.pos))
         fork.live = False
 
     def drop_fork(self, fork: LaneFork) -> None:
@@ -1002,7 +1035,7 @@ class CachePool:
         if kv is None:
             raise EngineStateError("rollback_lane requires a paged pool")
         kv.rollback(slot, pos)
-        self._pos = self._pos.at[slot].set(int(pos))
+        self._set_pos(self._pos.at[slot].set(int(pos)))
 
     def extract_lane(self, slot: int) -> dict:
         """A batch-1 COPY-VIEW of one contiguous lane (slices of the pool
@@ -1066,10 +1099,11 @@ class CachePool:
         kv = self._kv
         if kv is None or "k_pages" not in cache:
             return cache
-        try:
-            return kv.ensure_staging(cache, n_tokens)
-        except _PagesExhausted as e:
-            raise PoolExhausted(str(e), self.occupancy()) from None
+        with trace.span(trace.POOL_RESIDENCY):
+            try:
+                return kv.ensure_staging(cache, n_tokens)
+            except _PagesExhausted as e:
+                raise PoolExhausted(str(e), self.occupancy()) from None
 
     def release_staging(self) -> None:
         """Abort the in-flight admission stream, releasing its pages

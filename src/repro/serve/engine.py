@@ -65,7 +65,7 @@ from repro.core.dispatch import DegradationLadder
 from repro.core.pim_modes import (Mode, StepChoice, StepPlan, StepPolicy,
                                   StepSignals, plan_step)
 from repro.models import model as M
-from repro.serve import sampling
+from repro.serve import sampling, trace
 from repro.serve.api import (FINISH_CANCELLED, FINISH_EOS, FINISH_FAILED,
                              FINISH_LENGTH, FINISH_TIMEOUT, TERMINAL_STATES,
                              GenerationRequest, GenerationResult, RequestState)
@@ -103,6 +103,10 @@ class ScheduleEvent:
     verify_tokens: int = 0        # target positions scored: lanes x (K+1)
     spec_max_emitted: int = 0     # most tokens any one lane emitted
     draft_prefill_tokens: int = 0  # draft-lane (re)sync prefill tokens
+    # --- page pool --------------------------------------------------------
+    pool_copy_bytes: int = 0  # bytes written by the pool's eager updates
+    #                           from the previous step's end to this one's
+    #                           (whole arrays: CachePool.take_copy_bytes)
 
 
 class ScheduleReport(dict):
@@ -251,6 +255,7 @@ class Engine:
             ev.mode = self.mode.value
         ev.arrivals = self._take_arrivals()
         ev.queue_depth = self._queue_depth
+        ev.pool_copy_bytes = self.pool.take_copy_bytes()
         self.events.append(ev)
         # an idle event jumps the clock straight to the next arrival; every
         # other event is one engine step plus any injected slow penalty
@@ -260,6 +265,11 @@ class Engine:
     def _take_arrivals(self) -> int:
         n, self._arrived_unstamped = self._arrived_unstamped, 0
         return n
+
+    def _end_step(self) -> None:
+        """Charge the pool's eager copies made since the step's event was
+        pushed (its commit, retires and merges) to that event."""
+        self._last_ev.pool_copy_bytes += self.pool.take_copy_bytes()
 
     # ------------------------------------------------------------------ API
 
@@ -362,7 +372,8 @@ class Engine:
             if self._last_ev is not None:
                 self._last_ev.emitted_tokens += 1
             if r.on_token is not None:
-                r.on_token(tok)
+                with trace.span(trace.ON_TOKEN, req=s.req):
+                    r.on_token(tok)
             s.emitted += 1
             s.ctx += 1
             eos = self._eos[s.req]
@@ -416,17 +427,27 @@ class Engine:
             return pool.alloc(reqs[r], r, reused_tokens=rdy.reused,
                               ctx=rdy.ctx, emitted=len(results[r].tokens))
 
-        def place(rdy: _Ready) -> bool:
-            """Drop a fully prefilled request into a lane (False: parked)."""
+        def place(rdy: _Ready) -> int:
+            """Drop a fully prefilled request into a lane; returns the lane
+            (its first token still to emit), or -1 while it stays parked."""
             si = alloc_guarded(rdy)
             if si < 0:
-                return False
+                return -1
             pool.insert(si, rdy.cache, prompt=rdy.prompt or None)
             results[rdy.req].reused_prefix_tokens += rdy.reused
             results[rdy.req].state = RequestState.RUNNING
             cur_tok[si] = rdy.first_tok
-            emit(si, rdy.first_tok)
-            return True
+            return si
+
+        def take_next() -> int:
+            """Pop the queue's head for admission."""
+            r = queue.pop(0)
+            self._queue_depth = len(queue)
+            results[r].state = RequestState.ADMITTED
+            if results[r].admit_step is None:  # first admission only:
+                results[r].admit_step = self._clock  # re-queues after
+            #                          preemption never re-count waiting
+            return r
 
         def evict(r: int, state: RequestState, reason: str,
                   error: Optional[str] = None) -> None:
@@ -514,38 +535,47 @@ class Engine:
                               f"watchdog: step limit {limit} exceeded")
                         H["failures"] += 1
                 break
-            admit_arrivals()
-            sweep()
-            self._queue_depth = len(queue)
+            with trace.span(trace.SCHEDULE):
+                admit_arrivals()
+                sweep()
+                self._queue_depth = len(queue)
 
-            # -- nothing to run but arrivals still due: jump the clock to
-            # the next arrival as ONE zero-work idle event (pimsim prices
-            # it at zero busy time; the gap is recorded so replays map the
-            # engine clock onto the simulated timeline exactly)
-            if (not queue and stream is None and ready is None
-                    and not pool.has_work() and pending):
-                gap = reqs[pending[0]].arrival_step - self._clock
-                self._require(gap > 0, "idle jump planned with a due arrival")
-                self._push_event(ScheduleEvent(
-                    plan_step(self.mode, False, False, 0), 0, 0,
-                    idle_steps=gap))
-                continue
+                # -- nothing to run but arrivals still due: jump the clock
+                # to the next arrival as ONE zero-work idle event (pimsim
+                # prices it at zero busy time; the gap is recorded so
+                # replays map the engine clock onto the simulated timeline
+                # exactly)
+                if (not queue and stream is None and ready is None
+                        and not pool.has_work() and pending):
+                    gap = reqs[pending[0]].arrival_step - self._clock
+                    self._require(gap > 0,
+                                  "idle jump planned with a due arrival")
+                    self._push_event(ScheduleEvent(
+                        plan_step(self.mode, False, False, 0), 0, 0,
+                        idle_steps=gap))
+                    continue
 
-            # -- a parked request takes the first freed lane
-            if ready is not None and pool.free_slots():
-                if place(ready):
+                # -- a parked request takes the first freed lane
+                lane = None
+                if ready is not None and pool.free_slots():
+                    lane = place(ready)
+
+                # -- priority preemption: a parked admission outranking a
+                # running slot evicts the lowest-priority strict underdog
+                elif ready is not None:
+                    victims = sorted(
+                        (pool.get(si).priority, si)
+                        for si in pool.active_slots()
+                        if pool.get(si).priority < reqs[ready.req].priority)
+                    if victims:
+                        preempt(victims[0][1])
+                        continue
+            if lane is not None:
+                if lane >= 0:
+                    with trace.span(trace.EMIT):
+                        emit(lane, ready.first_tok)
                     ready = None
                 continue
-
-            # -- priority preemption: a parked admission outranking a
-            # running slot evicts the lowest-priority strict underdog
-            if ready is not None:
-                victims = sorted(
-                    (pool.get(si).priority, si) for si in pool.active_slots()
-                    if pool.get(si).priority < reqs[ready.req].priority)
-                if victims:
-                    preempt(victims[0][1])
-                    continue
 
             active = pool.active_slots()
             if not (queue or stream is not None or ready is not None
@@ -559,97 +589,97 @@ class Engine:
                 if self._admit_batch(queue, cur_tok, emit):
                     continue
 
-            # -- stage the next pending request (one admission in flight)
-            if stream is None and ready is None and queue:
-                r = queue.pop(0)
-                self._queue_depth = len(queue)
-                results[r].state = RequestState.ADMITTED
-                if results[r].admit_step is None:  # first admission only:
-                    results[r].admit_step = self._clock  # re-queues after
-                #                          preemption never re-count waiting
-                p = ext_prompt(r)
-                if not pool.policy.chunkable:
-                    # ring-cache configs: the W-slot ring is a steady-state
-                    # decode structure and cannot ingest multi-token chunks,
-                    # so admission is one full batch-1 prefill pass — a
-                    # serialization point in every mode.
-                    ready = self._prefill_one(r)
-                    continue
-                staging, skip = pool.stage_admission(p)
-                self._pending_reuse += skip
-                stream = _Prefill(
-                    req=r, toks=np.asarray([p], np.int32),
-                    cache=staging, off=skip, reused=skip)
+            # -- ring-cache configs: the W-slot ring is a steady-state
+            # decode structure and cannot ingest multi-token chunks, so
+            # admission is one full batch-1 prefill pass — a serialization
+            # point in every mode.
+            if (stream is None and ready is None and queue
+                    and not pool.policy.chunkable):
+                with trace.span(trace.ADMIT_BATCH, req=queue[0]):
+                    ready = self._prefill_one(take_next())
+                continue
 
-            # starvation-aware admission rate: each FREE lane is wasted decode
-            # bandwidth, so the controller lets the processor run a bigger
-            # prefill quantum per step the more lanes sit empty (1x when the
-            # stream merely runs ahead of retirement, up to `slots`x when the
-            # pool is starved). Under speculation lanes drain budgets up to
-            # (k+1)x faster than retirements alone suggest, so the quantum
-            # also scales with the EMITTED-token rate of the last decode
-            # event (`spec_refill`) — refilling by retirements only starves
-            # the very batch the verify GEMM win depends on. Quanta stay
-            # whole multiples of `chunk` with at most one sub-chunk tail per
-            # prompt, so the fused/prefill program shapes — and the jit
-            # cache — stay bounded by (slots + spec depth) x chunk.
-            c = 0
-            if stream is not None:
-                n_free = len(pool.free_slots())
-                boost = max(1, n_free)
-                if (self.spec_refill and spec_dec is not None
-                        and self._last_ev is not None
-                        and self._last_ev.decode_batch > 0):
-                    e = self._last_ev
-                    per_lane = -(-e.emitted_tokens // e.decode_batch)
-                    boost = max(boost, per_lane)
-                if stream.remaining >= self.chunk:
-                    c = self.chunk * min(boost,
-                                         stream.remaining // self.chunk)
-                else:
-                    c = stream.remaining
-            # -- per-step mode: the step policy (when installed) resolves
-            # LBIM-vs-HBCEM and speculative participation from the live
-            # queue-depth / deadline-slack signals; otherwise the static
-            # `mode` pin governs, with speculation always allowed.
-            choice = StepChoice(self.mode)
-            if self.step_policy is not None:
-                choice = self.step_policy.choose(StepSignals(
-                    clock=self._clock, active=len(active),
-                    free=len(pool.free_slots()),
-                    queue_depth=len(queue), pending_arrivals=len(pending),
-                    stream_remaining=(stream.remaining
-                                      if stream is not None else 0),
-                    backlog_prefill_tokens=sum(
-                        len(ext_prompt(r)) for r in queue),
-                    backlog_decode_tokens=sum(
-                        reqs[r].max_new_tokens - len(results[r].tokens)
-                        for r in queue),
-                    min_ttft_slack=ttft_slack()))
-            step_mode = choice.mode
-            # -- speculative draft depth per lane: the engine-wide k, capped
-            # by the request's own spec_k and by its remaining budget (the
-            # verify round emits at most k+1 tokens; the last budgeted token
-            # needs no speculation). Computed BEFORE planning so a round
-            # where nothing drafts is a plain decode step, not a mislabeled
-            # (and mispriced) SPEC_VERIFY. A policy that withholds spec this
-            # step leaves spec_ks empty — draft lanes stay synced through
-            # the plain path's note_emitted.
-            spec_ks: dict[int, int] = {}
-            if spec_dec is not None and choice.allow_spec:
-                for si in active:
-                    s = pool.get(si)
-                    rk = reqs[s.req].spec_k
-                    k_eff = min(self.spec.k if rk is None else rk,
-                                self.spec.k, s.budget - s.emitted - 1)
-                    if k_eff > 0:
-                        spec_ks[si] = k_eff
-            plan = plan_step(step_mode, bool(active), stream is not None, c,
-                             spec=bool(spec_ks))
-            if stream is not None and c > 0:
-                # page-in the stream's write blocks for this quantum
-                # (host-side residency; idempotent under ladder retries)
-                stream.cache = pool.staging_step_prep(stream.cache, c)
+            with trace.span(trace.SCHEDULE):
+                # -- stage the next pending request (one admission in flight)
+                if stream is None and ready is None and queue:
+                    r = take_next()
+                    p = ext_prompt(r)
+                    staging, skip = pool.stage_admission(p)
+                    self._pending_reuse += skip
+                    stream = _Prefill(
+                        req=r, toks=np.asarray([p], np.int32),
+                        cache=staging, off=skip, reused=skip)
+
+                # starvation-aware admission rate: each FREE lane is wasted
+                # decode bandwidth, so the controller lets the processor run a
+                # bigger prefill quantum per step the more lanes sit empty (1x
+                # when the stream merely runs ahead of retirement, up to
+                # `slots`x when the pool is starved). Under speculation lanes
+                # drain budgets up to (k+1)x faster than retirements alone
+                # suggest, so the quantum also scales with the EMITTED-token
+                # rate of the last decode event (`spec_refill`) — refilling by
+                # retirements only starves the very batch the verify GEMM win
+                # depends on. Quanta stay whole multiples of `chunk` with at
+                # most one sub-chunk tail per prompt, so the fused/prefill
+                # program shapes — and the jit cache — stay bounded by (slots +
+                # spec depth) x chunk.
+                c = 0
+                if stream is not None:
+                    n_free = len(pool.free_slots())
+                    boost = max(1, n_free)
+                    if (self.spec_refill and spec_dec is not None
+                            and self._last_ev is not None
+                            and self._last_ev.decode_batch > 0):
+                        e = self._last_ev
+                        per_lane = -(-e.emitted_tokens // e.decode_batch)
+                        boost = max(boost, per_lane)
+                    if stream.remaining >= self.chunk:
+                        c = self.chunk * min(boost,
+                                             stream.remaining // self.chunk)
+                    else:
+                        c = stream.remaining
+                # -- per-step mode: the step policy (when installed) resolves
+                # LBIM-vs-HBCEM and speculative participation from the live
+                # queue-depth / deadline-slack signals; otherwise the static
+                # `mode` pin governs, with speculation always allowed.
+                choice = StepChoice(self.mode)
+                if self.step_policy is not None:
+                    choice = self.step_policy.choose(StepSignals(
+                        clock=self._clock, active=len(active),
+                        free=len(pool.free_slots()),
+                        queue_depth=len(queue), pending_arrivals=len(pending),
+                        stream_remaining=(stream.remaining
+                                          if stream is not None else 0),
+                        backlog_prefill_tokens=sum(
+                            len(ext_prompt(r)) for r in queue),
+                        backlog_decode_tokens=sum(
+                            reqs[r].max_new_tokens - len(results[r].tokens)
+                            for r in queue),
+                        min_ttft_slack=ttft_slack()))
+                step_mode = choice.mode
+                # -- speculative draft depth per lane: the engine-wide k,
+                # capped by the request's own spec_k and by its remaining
+                # budget (the verify round emits at most k+1 tokens; the last
+                # budgeted token needs no speculation). Computed BEFORE
+                # planning so a round where nothing drafts is a plain decode
+                # step, not a mislabeled (and mispriced) SPEC_VERIFY. A policy
+                # that withholds spec this step leaves spec_ks empty — draft
+                # lanes stay synced through the plain path's note_emitted.
+                spec_ks: dict[int, int] = {}
+                if spec_dec is not None and choice.allow_spec:
+                    for si in active:
+                        s = pool.get(si)
+                        rk = reqs[s.req].spec_k
+                        k_eff = min(self.spec.k if rk is None else rk,
+                                    self.spec.k, s.budget - s.emitted - 1)
+                        if k_eff > 0:
+                            spec_ks[si] = k_eff
+                plan = plan_step(step_mode, bool(active), stream is not None,
+                                 c, spec=bool(spec_ks))
+                if stream is not None and c > 0:
+                    # page-in the stream's write blocks for this quantum
+                    # (host-side residency; idempotent under ladder retries)
+                    stream.cache = pool.staging_step_prep(stream.cache, c)
 
             # ---- guarded step execution: compute WITHOUT mutating pool or
             # stream; on a KernelFault (injected or a NaN/Inf trip), demote
@@ -684,44 +714,51 @@ class Engine:
                         # draft rollouts: functional w.r.t. the draft pool
                         # (only finish_round commits), so a retried attempt
                         # simply re-drafts; lane (re)sync is idempotent
-                        spec_dec.prune({si: pool.get(si).req
-                                        for si in active})
-                        dcfg = ladder.apply(spec_dec.draft_cfg)
-                        drafts = {}
-                        for si, k_eff in spec_ks.items():
-                            s = pool.get(si)
-                            spec_dec.ensure_lane(si, s.req, reqs[s.req],
-                                                 ext_prompt(s.req), dcfg)
-                            drafts[si] = spec_dec.rollout(si, k_eff, dcfg)
-                        span = 1 + max(len(d) for d in drafts.values())
-                        forks = {si: pool.fork_lane(si) for si in active}
-                        pos_before = {si: forks[si].pos for si in active}
-                    feed = jnp.asarray(cur_tok)[:, None]
-                    if plan.fused:
-                        self._require(stream is not None,
-                                      "fused step planned without an "
-                                      "admission stream in flight")
-                        chunk_toks = jnp.asarray(
-                            stream.toks[:, stream.off:stream.off + c])
-                        logits, new_cache, pre_logits, new_scache = \
-                            interleave.fused_step(
-                                dparams, pool.views(span=1), feed,
-                                stream.cache, chunk_toks, cfg_step)
-                    else:
-                        if plan.decode:
-                            logits, new_cache = interleave.decode_only_step(
-                                dparams, pool.views(span=1), feed,
-                                cfg_step)
-                        if plan.prefill_chunk:
+                        with trace.span(trace.SPEC):
+                            spec_dec.prune({si: pool.get(si).req
+                                            for si in active})
+                            dcfg = ladder.apply(spec_dec.draft_cfg)
+                            drafts = {}
+                            for si, k_eff in spec_ks.items():
+                                s = pool.get(si)
+                                spec_dec.ensure_lane(si, s.req, reqs[s.req],
+                                                     ext_prompt(s.req), dcfg)
+                                drafts[si] = spec_dec.rollout(si, k_eff,
+                                                              dcfg)
+                            span = 1 + max(len(d) for d in drafts.values())
+                            forks = {si: pool.fork_lane(si) for si in active}
+                            pos_before = {si: forks[si].pos for si in active}
+                    views = pool.views(span=1) if plan.decode else None
+                    with trace.span(trace.DISPATCH, step=len(self.events)):
+                        feed = jnp.asarray(cur_tok)[:, None]
+                        if plan.fused:
                             self._require(stream is not None,
-                                          "prefill chunk planned without an "
+                                          "fused step planned without an "
                                           "admission stream in flight")
                             chunk_toks = jnp.asarray(
                                 stream.toks[:, stream.off:stream.off + c])
-                            pre_logits, new_scache = \
-                                interleave.prefill_chunk_step(
-                                    dparams, stream.cache, chunk_toks,
-                                    cfg_step)
+                            logits, new_cache, pre_logits, new_scache = \
+                                interleave.fused_step(
+                                    dparams, views, feed, stream.cache,
+                                    chunk_toks, cfg_step)
+                        else:
+                            if plan.decode:
+                                logits, new_cache = \
+                                    interleave.decode_only_step(
+                                        dparams, views, feed, cfg_step)
+                            if plan.prefill_chunk:
+                                self._require(
+                                    stream is not None,
+                                    "prefill chunk planned without an "
+                                    "admission stream in flight")
+                                chunk_toks = jnp.asarray(
+                                    stream.toks[:, stream.off:stream.off + c])
+                                pre_logits, new_scache = \
+                                    interleave.prefill_chunk_step(
+                                        dparams, stream.cache, chunk_toks,
+                                        cfg_step)
+                    # the step's input pages must not outlive its commit
+                    views = None
                     if plan.spec and span > 1:
                         # Verify scores every span position through the SAME
                         # (slots, 1) decode program plain decode runs, each
@@ -734,21 +771,22 @@ class Engine:
                         # 1.0.) On hardware the K+1 scores fuse into one
                         # weights-resident GEMM; pimsim prices the event
                         # that way (`latency.verify_step_time`).
-                        vlogits = [logits]
-                        pool.commit(new_cache)
-                        new_cache = None
-                        for j in range(1, span):
-                            feed_j = np.zeros((self.slots, 1), np.int32)
-                            for si, d in drafts.items():
-                                if j - 1 < len(d):
-                                    feed_j[si, 0] = d[j - 1]
-                            lg_j, nc_j = interleave.decode_only_step(
-                                dparams, pool.views(span=1),
-                                jnp.asarray(feed_j), cfg_step)
-                            pool.commit(nc_j)
-                            vlogits.append(lg_j)
-                        logits = jnp.concatenate(
-                            [jnp.asarray(lg) for lg in vlogits], axis=1)
+                        with trace.span(trace.SPEC):
+                            vlogits = [logits]
+                            pool.commit(new_cache)
+                            new_cache = None
+                            for j in range(1, span):
+                                feed_j = np.zeros((self.slots, 1), np.int32)
+                                for si, d in drafts.items():
+                                    if j - 1 < len(d):
+                                        feed_j[si, 0] = d[j - 1]
+                                lg_j, nc_j = interleave.decode_only_step(
+                                    dparams, pool.views(span=1),
+                                    jnp.asarray(feed_j), cfg_step)
+                                pool.commit(nc_j)
+                                vlogits.append(lg_j)
+                            logits = jnp.concatenate(
+                                [jnp.asarray(lg) for lg in vlogits], axis=1)
                     if faults is not None:
                         f = faults.take(self._clock, "nan_logits",
                                         pred=lambda _: ladder.can_degrade())
@@ -759,8 +797,11 @@ class Engine:
                                 logits = logits * bad
                             elif pre_logits is not None:
                                 pre_logits = pre_logits * bad
-                    if self.nan_guard and not _finite(logits, active,
-                                                      pre_logits):
+                    finite = True
+                    if self.nan_guard:
+                        with trace.span(trace.GUARD):
+                            finite = _finite(logits, active, pre_logits)
+                    if not finite:
                         ladder.record_nan()
                         raise KernelFault(
                             "decode_attention",
@@ -839,6 +880,7 @@ class Engine:
                     results[stream.req].finish_step = self._clock
                     stream = None
                     pool.release_staging()
+                self._end_step()
                 continue
 
             if new_cache is not None:
@@ -852,14 +894,17 @@ class Engine:
                 cur_tok = self._spec_accept(logits, active, drafts, forks,
                                             pos_before, cur_tok, ev, emit)
             elif plan.decode:
-                tok = self._sample_slots(logits, active)
+                with trace.span(trace.SAMPLE):
+                    tok = self._sample_slots(logits, active)
                 cur_tok = tok.astype(np.int32)
-                for si in active:
-                    emit(si, int(tok[si]))
-                    if spec_dec is not None:
-                        # keep draft lanes in sync across plain decode steps
-                        # (spec suppressed this round) without a resync
-                        spec_dec.note_emitted(si, [int(tok[si])])
+                with trace.span(trace.EMIT):
+                    for si in active:
+                        emit(si, int(tok[si]))
+                        if spec_dec is not None:
+                            # keep draft lanes in sync across plain decode
+                            # steps (spec suppressed this round) without a
+                            # resync
+                            spec_dec.note_emitted(si, [int(tok[si])])
 
             if stream is not None and stream.remaining == 0:
                 # chunks are unpadded, so the last chunk's final position IS
@@ -869,13 +914,15 @@ class Engine:
                               "admission stream drained without prefill "
                               "logits to seed its first token")
                 r = stream.req
-                first = self._first_tokens(
-                    pre_logits[:, -1:, :], [r],
-                    offsets=[len(results[r].tokens)])[0]
+                with trace.span(trace.SAMPLE):
+                    first = self._first_tokens(
+                        pre_logits[:, -1:, :], [r],
+                        offsets=[len(results[r].tokens)])[0]
                 ready = _Ready(r, stream.cache, first, stream.reused,
                                prompt=[int(t) for t in stream.toks[0]],
                                ctx=int(stream.toks.shape[1]))
                 stream = None
+            self._end_step()
 
         self._in_serve = False
         pool.release_staging()  # defensive: no handle outlives a serve()
@@ -985,36 +1032,39 @@ class Engine:
         span = logits.shape[1]
         emitted_at = {si: pool.get(si).emitted for si in active}
         samp = np.zeros((self.slots, span), np.int32)
-        for j in range(span):
-            samp[:, j] = self._sample_slots(
-                logits[:, j:j + 1, :], active,
-                offsets={si: emitted_at[si] + j for si in active})
+        with trace.span(trace.SAMPLE):
+            for j in range(span):
+                samp[:, j] = self._sample_slots(
+                    logits[:, j:j + 1, :], active,
+                    offsets={si: emitted_at[si] + j for si in active})
         new_cur = cur_tok.copy()
-        for si in active:
-            d = drafts.get(si, [])
-            a = 0
-            while a < len(d) and int(d[a]) == int(samp[si, a]):
-                a += 1
-            r = pool.get(si).req
-            emitted: list[int] = []
-            for j in range(a + 1):
-                emitted.append(int(samp[si, j]))
-                emit(si, int(samp[si, j]))
-                if pool.get(si).state != ACTIVE:
-                    break  # eos/budget: exactly where non-spec would stop
-            results[r].spec_proposed += len(d)
-            results[r].spec_accepted += a
-            ev.spec_accepted += a
-            ev.spec_max_emitted = max(ev.spec_max_emitted, len(emitted))
-            if pool.get(si).state == ACTIVE:
-                pool.rollback_lane(si, pos_before[si] + len(emitted))
-                new_cur[si] = emitted[-1]
-                spec_dec.finish_round(si, emitted)
-            else:
-                # retire already released the lane's pages; the fork below
-                # still holds its own refs — dropped once, like every round
-                spec_dec.retire_lane(si)
-            pool.drop_fork(forks[si])
+        with trace.span(trace.EMIT):
+            for si in active:
+                d = drafts.get(si, [])
+                a = 0
+                while a < len(d) and int(d[a]) == int(samp[si, a]):
+                    a += 1
+                r = pool.get(si).req
+                emitted: list[int] = []
+                for j in range(a + 1):
+                    emitted.append(int(samp[si, j]))
+                    emit(si, int(samp[si, j]))
+                    if pool.get(si).state != ACTIVE:
+                        break  # eos/budget: exactly where non-spec would stop
+                results[r].spec_proposed += len(d)
+                results[r].spec_accepted += a
+                ev.spec_accepted += a
+                ev.spec_max_emitted = max(ev.spec_max_emitted, len(emitted))
+                if pool.get(si).state == ACTIVE:
+                    pool.rollback_lane(si, pos_before[si] + len(emitted))
+                    new_cur[si] = emitted[-1]
+                    spec_dec.finish_round(si, emitted)
+                else:
+                    # retire already released the lane's pages; the fork
+                    # below still holds its own refs — dropped once, like
+                    # every round
+                    spec_dec.retire_lane(si)
+                pool.drop_fork(forks[si])
         return new_cur
 
     def _first_tokens(self, logits, rids: list[int],
@@ -1056,8 +1106,9 @@ class Engine:
         pcache["pos"] = jnp.asarray([toks.shape[1]], jnp.int32)
         self._push_event(ScheduleEvent(
             plan_step(self.mode, False, True, toks.shape[1]), 0, toks.shape[1]))
-        first = self._first_tokens(logits, [r],
-                                   offsets=[len(self._results[r].tokens)])[0]
+        with trace.span(trace.SAMPLE):
+            first = self._first_tokens(
+                logits, [r], offsets=[len(self._results[r].tokens)])[0]
         return _Ready(r, pcache, first, prompt=p, ctx=len(p))
 
     def _admit_batch(self, queue, cur_tok, emit) -> bool:
@@ -1078,48 +1129,59 @@ class Engine:
         reqs = self._reqs
         results = self._results
         pool = self.pool
-        if (self.fault_plan is not None and
-                self.fault_plan.take(self._clock, "alloc_fail") is not None):
-            self._health["injected_faults"] += 1
-            return False
-        free = pool.free_slots()
-        ext = {r: list(reqs[r].prompt) + results[r].tokens for r in queue}
-        take: list[int] = []
-        while queue and len(take) < len(free):
-            if pool.peek_prefix(ext[queue[0]]) > 0:
-                break
-            take.append(queue.pop(0))
-        if not take:
-            return False
-        lens = [len(ext[r]) for r in take]
-        groups = ([[r] for r in take]
-                  if not pool.policy.ragged_batch_ok and len(set(lens)) > 1
-                  else [take])
+        with trace.span(trace.ADMIT_BATCH):
+            if (self.fault_plan is not None and self.fault_plan.take(
+                    self._clock, "alloc_fail") is not None):
+                self._health["injected_faults"] += 1
+                return False
+            free = pool.free_slots()
+            ext = {r: list(reqs[r].prompt) + results[r].tokens for r in queue}
+            take: list[int] = []
+            while queue and len(take) < len(free):
+                if pool.peek_prefix(ext[queue[0]]) > 0:
+                    break
+                take.append(queue.pop(0))
+            if not take:
+                return False
+            lens = [len(ext[r]) for r in take]
+            groups = ([[r] for r in take]
+                      if not pool.policy.ragged_batch_ok and len(set(lens)) > 1
+                      else [take])
+        # each group's prefill is a step; a lane's first token is emitted
+        # as soon as the lane holds it, outside the admission's span
         for group in groups:
-            for r in group:
-                results[r].state = RequestState.ADMITTED
-                if results[r].admit_step is None:  # set-once, as staged path
-                    results[r].admit_step = self._clock
-            glens = [len(ext[r]) for r in group]
-            toks = np.zeros((len(group), max(glens)), np.int32)
+            with trace.span(trace.ADMIT_BATCH, req=group[0]):
+                for r in group:
+                    results[r].state = RequestState.ADMITTED
+                    if results[r].admit_step is None:  # set-once, as staged
+                        results[r].admit_step = self._clock
+                glens = [len(ext[r]) for r in group]
+                toks = np.zeros((len(group), max(glens)), np.int32)
+                for j, r in enumerate(group):
+                    toks[j, : len(ext[r])] = ext[r]
+                seq_lens = jnp.asarray(glens, jnp.int32)
+                logits, pcache = M.prefill(
+                    self.params, {"tokens": jnp.asarray(toks)}, self.cfg,
+                    self.max_len,
+                    seq_lens=seq_lens if len(set(glens)) > 1 else None)
+                pcache["pos"] = seq_lens
+                self._push_event(ScheduleEvent(
+                    plan_step(self.mode, False, True, sum(glens)), 0,
+                    sum(glens)))
+                with trace.span(trace.SAMPLE):
+                    first = self._first_tokens(
+                        logits, group,
+                        offsets=[len(results[r].tokens) for r in group])
             for j, r in enumerate(group):
-                toks[j, : len(ext[r])] = ext[r]
-            seq_lens = jnp.asarray(glens, jnp.int32)
-            logits, pcache = M.prefill(
-                self.params, {"tokens": jnp.asarray(toks)}, self.cfg, self.max_len,
-                seq_lens=seq_lens if len(set(glens)) > 1 else None)
-            pcache["pos"] = seq_lens
-            self._push_event(ScheduleEvent(
-                plan_step(self.mode, False, True, sum(glens)), 0, sum(glens)))
-            first = self._first_tokens(
-                logits, group, offsets=[len(results[r].tokens) for r in group])
-            for j, r in enumerate(group):
-                si = pool.alloc(reqs[r], r, ctx=len(ext[r]),
-                                emitted=len(results[r].tokens))
-                pool.insert(si, pcache, src_slot=j, prompt=ext[r])
-                results[r].state = RequestState.RUNNING
-                cur_tok[si] = first[j]
-                emit(si, first[j])
+                with trace.span(trace.ADMIT_BATCH, req=r):
+                    si = pool.alloc(reqs[r], r, ctx=len(ext[r]),
+                                    emitted=len(results[r].tokens))
+                    pool.insert(si, pcache, src_slot=j, prompt=ext[r])
+                    results[r].state = RequestState.RUNNING
+                    cur_tok[si] = first[j]
+                with trace.span(trace.EMIT):
+                    emit(si, first[j])
+            self._end_step()
         return True
 
     # ------------------------------------------------------------- reporting
