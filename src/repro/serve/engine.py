@@ -107,6 +107,9 @@ class ScheduleEvent:
     pool_copy_bytes: int = 0  # bytes written by the pool's eager updates
     #                           from the previous step's end to this one's
     #                           (whole arrays: CachePool.take_copy_bytes)
+    # --- host syncs -------------------------------------------------------
+    result_pulls: int = 0  # blocking device-to-host reads of the NaN guard
+    #                        and token selection (retried attempts included)
 
 
 class ScheduleReport(dict):
@@ -117,20 +120,6 @@ class ScheduleReport(dict):
         out = dict(self)
         out["modes"] = sorted(out["modes"])
         return out
-
-
-def _finite(logits, active, pre_logits) -> bool:
-    """NaN/Inf logit guard: only positions that can become tokens are
-    checked — ACTIVE decode lanes (free lanes decode garbage by design) and
-    the admission chunk's final position (the one that seeds a first token).
-    """
-    if logits is not None and active:
-        if not np.isfinite(np.asarray(logits)[np.asarray(active)]).all():
-            return False
-    if pre_logits is not None:
-        if not np.isfinite(np.asarray(pre_logits[:, -1:, :])).all():
-            return False
-    return True
 
 
 @dataclass
@@ -307,8 +296,10 @@ class Engine:
         self._reqs = reqs
         self._eos = [r.eos_id if r.eos_id is not None else self.cfg.eos_id
                      for r in reqs]
-        self._base_keys = [sampling.request_key(r.sampling.seed, r.prompt)
-                           for r in reqs]
+        # host copies, made once: a step's sampled lanes upload theirs
+        self._base_keys = [
+            np.asarray(sampling.request_key(r.sampling.seed, r.prompt))
+            if r.sampling.temperature > 0 else None for r in reqs]
         results = [GenerationResult(prompt_len=len(r.prompt),
                                     arrival_step=r.arrival_step)
                    for r in reqs]
@@ -687,7 +678,7 @@ class Engine:
             # a clean step's outputs — a retried step never double-appends.
             dparams = self.serving.decode_params
             logits = pre_logits = new_cache = new_scache = None
-            attempts, step_ok = 0, False
+            attempts, pulls, step_ok = 0, 0, False
             # -- each attempt forks every verify participant afresh: the
             # branch's appends copy-on-write against the snapshot, and each
             # fork is spent exactly once — restored (bit-identical row and
@@ -757,6 +748,22 @@ class Engine:
                                     interleave.prefill_chunk_step(
                                         dparams, stream.cache, chunk_toks,
                                         cfg_step)
+                        # the token rule's inputs go up while the step runs:
+                        # a decode lane keys on its request's emitted count,
+                        # as does the admission stream's last position (its
+                        # first token once the stream drains)
+                        lanes = first = None
+                        if plan.decode:
+                            lanes = self._lanes([
+                                (pool.get(si).req, pool.get(si).emitted)
+                                if si in active else None
+                                for si in range(self.slots)])
+                        if plan.prefill_chunk:
+                            first = self._lanes(
+                                [(stream.req, len(results[stream.req].tokens))])
+                        if span == 1:
+                            out = self._check(logits, lanes, pre_logits,
+                                              first)
                     # the step's input pages must not outlive its commit
                     views = None
                     if plan.spec and span > 1:
@@ -787,21 +794,13 @@ class Engine:
                                 vlogits.append(lg_j)
                             logits = jnp.concatenate(
                                 [jnp.asarray(lg) for lg in vlogits], axis=1)
-                    if faults is not None:
-                        f = faults.take(self._clock, "nan_logits",
-                                        pred=lambda _: ladder.can_degrade())
-                        if f is not None:
-                            H["injected_faults"] += 1
-                            bad = jnp.float32(jnp.nan)
-                            if logits is not None:
-                                logits = logits * bad
-                            elif pre_logits is not None:
-                                pre_logits = pre_logits * bad
-                    finite = True
-                    if self.nan_guard:
-                        with trace.span(trace.GUARD):
-                            finite = _finite(logits, active, pre_logits)
-                    if not finite:
+                            out = self._check(logits, lanes, pre_logits,
+                                              first)
+                    # the step's one blocking read: tokens and finite flag
+                    with trace.span(trace.SAMPLE):
+                        got = np.asarray(out)
+                    pulls += 1
+                    if not got[-1]:
                         ladder.record_nan()
                         raise KernelFault(
                             "decode_attention",
@@ -838,6 +837,7 @@ class Engine:
                 max((pool.get(i).ctx for i in active), default=0),
                 self._take_reuse(), attempts=attempts, slow_penalty=slow,
                 degraded=ladder.is_degraded(), mode=step_mode.value,
+                result_pulls=pulls,
                 # a spec step is priced as one weights-resident verify GEMM,
                 # not K+1 split-KV GEMV sweeps, so it doesn't fan out
                 kv_splits=(max(1, self.cfg.decode_kv_splits)
@@ -890,12 +890,15 @@ class Engine:
                 stream.cache = new_scache
                 stream.off += c
 
+            if plan.decode:
+                # the lanes' tokens lead the result, one row per slot
+                samp = got[:logits.shape[0] * logits.shape[1]].reshape(
+                    logits.shape[:2])
             if plan.spec:
-                cur_tok = self._spec_accept(logits, active, drafts, forks,
+                cur_tok = self._spec_accept(samp, active, drafts, forks,
                                             pos_before, cur_tok, ev, emit)
             elif plan.decode:
-                with trace.span(trace.SAMPLE):
-                    tok = self._sample_slots(logits, active)
+                tok = samp[:, 0]
                 cur_tok = tok.astype(np.int32)
                 with trace.span(trace.EMIT):
                     for si in active:
@@ -914,11 +917,8 @@ class Engine:
                               "admission stream drained without prefill "
                               "logits to seed its first token")
                 r = stream.req
-                with trace.span(trace.SAMPLE):
-                    first = self._first_tokens(
-                        pre_logits[:, -1:, :], [r],
-                        offsets=[len(results[r].tokens)])[0]
-                ready = _Ready(r, stream.cache, first, stream.reused,
+                # the stream's one token sits just before the flag
+                ready = _Ready(r, stream.cache, int(got[-2]), stream.reused,
                                prompt=[int(t) for t in stream.toks[0]],
                                ctx=int(stream.toks.shape[1]))
                 stream = None
@@ -962,58 +962,70 @@ class Engine:
 
     # --------------------------------------------------------------- sampling
 
-    def _sample_slots(self, logits, active,
-                      offsets: Optional[dict] = None) -> np.ndarray:
-        """One pool-wide sampling step: per-slot params/keys from the table.
+    def _lanes(self, rows: Sequence[Optional[tuple[int, int]]]
+               ) -> sampling.Lanes:
+        """The token rule's inputs for ``rows``: each a ``(request, index)``
+        pair — the request it serves and its emitted-token index, which keys
+        the row's token — or None for a free row, which emits the pad token.
 
-        When every active lane is greedy (the default), this is a single
-        argmax (``greedy_masked`` — sample_masked's temperature=0 fast path):
-        no RNG keys are derived and no top-k/top-p filter runs.
-
-        ``offsets`` overrides each lane's RNG-lane key index (slot -> absolute
-        emitted-token index); the default is the slot's current ``emitted``
-        count. A speculative verify round samples position ``j`` with offset
-        ``emitted + j`` — exactly the key non-spec decode would use when it
-        reached that token.
+        When every row is greedy (the default) this is the done mask alone:
+        ``check_and_sample`` then runs the plain masked argmax, with no keys
+        and no top-k/top-p filter.
         """
-        self._require(self.pool is not None, "sampling without a pool")
-        pool = self.pool
-        done = np.ones((self.slots,), bool)
-        done[active] = False
-        if all(self._reqs[pool.get(si).req].sampling.temperature <= 0
-               for si in active):
-            return np.asarray(sampling.greedy_masked(logits, jnp.asarray(done)))
-        temps = np.zeros((self.slots,), np.float32)
-        tks = np.zeros((self.slots,), np.int32)
-        tps = np.ones((self.slots,), np.float32)
-        keys = np.zeros((self.slots, 2), np.uint32)
-        sampled = []
-        for si in active:
-            sp = self._reqs[pool.get(si).req].sampling
-            temps[si] = sp.temperature
-            tks[si] = sp.top_k
-            tps[si] = sp.top_p
+        done = np.asarray([row is None for row in rows])
+        sps = [None if row is None else self._reqs[row[0]].sampling
+               for row in rows]
+        if all(sp is None or sp.temperature <= 0 for sp in sps):
+            return sampling.Lanes(jnp.asarray(done))
+        n = len(rows)
+        temps = np.zeros((n,), np.float32)
+        tks = np.zeros((n,), np.int32)
+        tps = np.ones((n,), np.float32)
+        keys = np.zeros((n, 2), np.uint32)
+        offs = np.zeros((n,), np.uint32)
+        for i, (row, sp) in enumerate(zip(rows, sps)):
+            if sp is None:
+                continue
+            temps[i], tks[i], tps[i] = sp.temperature, sp.top_k, sp.top_p
             if sp.temperature > 0:
-                sampled.append(si)
-        # one batched fold_in for every sampled lane's token key (not one
-        # eager dispatch per lane per step)
-        offs = [pool.get(si).emitted if offsets is None else offsets[si]
-                for si in sampled]
-        keys[np.asarray(sampled)] = np.asarray(jax.vmap(jax.random.fold_in)(
-            jnp.stack([self._base_keys[pool.get(si).req] for si in sampled]),
-            jnp.asarray(offs, jnp.uint32)))
-        return np.asarray(sampling.sample_masked(
-            logits, jnp.asarray(done), keys=jnp.asarray(keys),
-            temperature=jnp.asarray(temps), top_k=jnp.asarray(tks),
-            top_p=jnp.asarray(tps)))
+                keys[i], offs[i] = self._base_keys[row[0]], row[1]
+        return sampling.Lanes(
+            jnp.asarray(done), temperature=jnp.asarray(temps),
+            top_k=jnp.asarray(tks), top_p=jnp.asarray(tps),
+            keys=jnp.asarray(keys), offsets=jnp.asarray(offs))
 
-    def _spec_accept(self, logits, active, drafts, forks, pos_before,
+    def _check(self, logits, lanes, pre_logits, first) -> jax.Array:
+        """Enqueue the step's check-and-sample program on its final logits:
+        the decode lanes' (``lanes``) and the admission chunk's last
+        position (``first``). Only rows that can become tokens are checked:
+        active lanes (free lanes decode garbage by design) and that position.
+        An injected ``nan_logits`` fault poisons the logits first."""
+        faults = self.fault_plan
+        if faults is not None:
+            f = faults.take(self._clock, "nan_logits",
+                            pred=lambda _: self.ladder.can_degrade())
+            if f is not None:
+                self._health["injected_faults"] += 1
+                bad = jnp.float32(jnp.nan)
+                if logits is not None:
+                    logits = logits * bad
+                elif pre_logits is not None:
+                    pre_logits = pre_logits * bad
+        groups = []
+        if logits is not None:
+            groups.append((logits, lanes))
+        if pre_logits is not None:
+            groups.append((pre_logits[:, -1:, :], first))
+        return sampling.check_and_sample(groups, check=self.nan_guard)
+
+    def _spec_accept(self, samp, active, drafts, forks, pos_before,
                      cur_tok, ev: ScheduleEvent, emit) -> np.ndarray:
         """Token-matching rejection acceptance for one verify round.
 
-        The target samples EVERY position ``j`` of the (slots, K+1, V) verify
-        logits on the request's own RNG lane at absolute index ``emitted + j``
-        — the key non-spec decode would use when it reached that token.
+        ``samp`` (slots, K+1) holds the target's sample at EVERY position
+        ``j`` of the verify logits, drawn on the request's own RNG lane at
+        absolute index ``emitted + j`` — the key non-spec decode would use
+        when it reached that token.
         Draft token ``d_j`` is accepted iff it equals the target's sample at
         the position that fed it. Verify positions run the plain decode
         program on an identical context, so the emitted stream ``s_0..s_a``
@@ -1029,14 +1041,6 @@ class Engine:
         pool = self.pool
         spec_dec = self.spec_dec
         results = self._results
-        span = logits.shape[1]
-        emitted_at = {si: pool.get(si).emitted for si in active}
-        samp = np.zeros((self.slots, span), np.int32)
-        with trace.span(trace.SAMPLE):
-            for j in range(span):
-                samp[:, j] = self._sample_slots(
-                    logits[:, j:j + 1, :], active,
-                    offsets={si: emitted_at[si] + j for si in active})
         new_cur = cur_tok.copy()
         with trace.span(trace.EMIT):
             for si in active:
@@ -1068,28 +1072,19 @@ class Engine:
         return new_cur
 
     def _first_tokens(self, logits, rids: list[int],
-                      offsets: Optional[list[int]] = None) -> list[int]:
-        """Sample each request's prefill-seeded first token.
+                      offsets: list[int]) -> list[int]:
+        """Sample each request's prefill-seeded first token from its row of
+        the prefill's last-position ``logits`` (G, 1, V), in one program and
+        one pull.
 
         ``offsets`` are the requests' absolute emitted-token indices — 0 on
         first admission, the emitted count on a preemption resume, so the
         RNG-lane key stream continues exactly where eviction cut it off.
         """
-        g = len(rids)
-        offs = offsets if offsets is not None else [0] * g
-        sps = [self._reqs[r].sampling for r in rids]
-        if all(sp.temperature <= 0 for sp in sps):
-            return [int(t) for t in np.asarray(sampling.greedy(logits))]
-        keys = np.stack([
-            np.asarray(sampling.token_key(self._base_keys[r], off))
-            if sp.temperature > 0 else np.zeros((2,), np.uint32)
-            for r, sp, off in zip(rids, sps, offs)]).astype(np.uint32)
-        tok = sampling.sample_masked(
-            logits, jnp.zeros((g,), bool), keys=jnp.asarray(keys),
-            temperature=jnp.asarray([sp.temperature for sp in sps], jnp.float32),
-            top_k=jnp.asarray([sp.top_k for sp in sps], jnp.int32),
-            top_p=jnp.asarray([sp.top_p for sp in sps], jnp.float32))
-        return [int(t) for t in np.asarray(tok)]
+        got = np.asarray(sampling.check_and_sample(
+            [(logits, self._lanes(list(zip(rids, offsets))))], check=False))
+        self._last_ev.result_pulls += 1
+        return [int(t) for t in got[:len(rids)]]
 
     # ------------------------------------------------------- admission paths
 

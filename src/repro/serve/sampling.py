@@ -14,10 +14,15 @@ scheduling-dependent (slot index, admission order, sibling retirement,
 global step count) enters the derivation, so the token stream of a request
 is a pure function of (params, prompt, SamplingParams) — the property the
 continuous-batching engine's determinism tests pin down.
+
+:func:`check_and_sample` is what the engine runs each step: the NaN guard
+and both rules in one device program, whose small result the host reads
+once.
 """
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -122,6 +127,74 @@ def greedy_masked(logits: jax.Array, done: jax.Array, pad_id: int = 0) -> jax.Ar
     cross-mode token identity exact.
     """
     return jnp.where(jnp.asarray(done), jnp.int32(pad_id), greedy(logits))
+
+
+class Lanes(NamedTuple):
+    """Per-row inputs of :func:`check_and_sample`'s token rule.
+
+    ``temperature`` None selects the all-greedy rule (:func:`greedy_masked`:
+    no keys, no filter). Otherwise every row carries its sampling params and
+    its request's base key, and position ``t`` of row ``b`` draws on
+    ``token_key(keys[b], offsets[b] + t)``.
+    """
+    done: jax.Array                          # (B,) bool — rows that emit the pad token
+    temperature: Optional[jax.Array] = None  # (B,) f32
+    top_k: Optional[jax.Array] = None        # (B,) int32
+    top_p: Optional[jax.Array] = None        # (B,) f32
+    keys: Optional[jax.Array] = None         # (B, 2) uint32 — request base keys
+    offsets: Optional[jax.Array] = None      # (B,) uint32 — emitted-token index
+
+
+def _select(logits, lanes: Lanes, rules) -> jax.Array:
+    """(B, T, V) logits → (B, T) int32: each position through the rows'
+    rule as a (B, 1, V) step of its own, so a speculative verify position
+    draws exactly what a plain decode step would."""
+    greedy_rule, sampled_rule = rules
+    cols = []
+    for t in range(logits.shape[1]):
+        lg = logits[:, t:t + 1, :]
+        if lanes.temperature is None:
+            cols.append(greedy_rule(lg, lanes.done))
+            continue
+        keys = jax.vmap(token_key)(lanes.keys, lanes.offsets + t)
+        cols.append(sampled_rule(
+            lg, lanes.done, keys=keys, temperature=lanes.temperature,
+            top_k=lanes.top_k, top_p=lanes.top_p))
+    return jnp.stack(cols, axis=1)
+
+
+@functools.partial(jax.jit, static_argnames=("check", "rules"))
+def _check_and_sample(groups, *, check: bool, rules) -> jax.Array:
+    parts = []
+    ok = jnp.bool_(True)
+    for logits, lanes in groups:
+        parts.append(_select(logits, lanes, rules).reshape(-1))
+        if check:
+            ok &= jnp.all(jnp.isfinite(logits) | lanes.done[:, None, None])
+    parts.append(ok.astype(jnp.int32)[None])
+    return jnp.concatenate(parts)
+
+
+def check_and_sample(groups, *, check: bool = True) -> jax.Array:
+    """The engine step's NaN guard and token selection as ONE device program.
+
+    ``groups`` is a tuple of ``(logits, lanes)`` pairs: the step's decode
+    logits ``(slots, T, V)`` (``T > 1`` for speculative verify positions) with
+    one :class:`Lanes` row per slot, and/or the last positions ``(G, 1, V)``
+    of prefill logits with one row per request they seed. Returns one
+    ``(sum B*T + 1,)`` int32 vector for a single pull: each group's tokens
+    row-major, in order, then a flag that is 1 iff every logit of every row
+    not ``done`` is finite (always 1 with ``check`` False, which computes
+    nothing for it).
+
+    Which program runs is static — which groups the step holds, each group's
+    rule (all-greedy or sampled), ``check`` — so the all-greedy step stays a
+    plain masked argmax. The two rules are looked up here on every call and
+    passed static, so a replaced :func:`greedy_masked` (a test's planted
+    fault) compiles its own program and never lingers in the jit cache.
+    """
+    return _check_and_sample(tuple(groups), check=check,
+                             rules=(greedy_masked, sample_masked))
 
 
 def sample(logits: jax.Array, rng: jax.Array, temperature: float = 1.0,

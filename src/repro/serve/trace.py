@@ -21,8 +21,7 @@ import jax
 
 SCHEDULE = "engine.schedule"              # loop head: arrivals, placement, staging, planning
 DISPATCH = "engine.dispatch"              # enqueue the step's programs
-GUARD = "engine.guard"                    # NaN guard's pull of the logits
-SAMPLE = "engine.sample"                  # sampling program and its pull
+SAMPLE = "engine.sample"                  # the one read of check-and-sample
 EMIT = "engine.emit"                      # per-lane token bookkeeping
 ON_TOKEN = "engine.on_token"              # the caller's callback
 POOL_COMMIT = "engine.pool.commit"        # CachePool.commit
@@ -31,7 +30,7 @@ POOL_RESIDENCY = "engine.pool.residency"  # page-in and copy-on-write
 ADMIT_BATCH = "engine.admit_batch"        # full-prompt prefill admissions
 SPEC = "engine.spec"                      # draft rollouts and verify sub-steps
 
-SPANS = (SCHEDULE, DISPATCH, GUARD, SAMPLE, EMIT, ON_TOKEN, POOL_COMMIT,
+SPANS = (SCHEDULE, DISPATCH, SAMPLE, EMIT, ON_TOKEN, POOL_COMMIT,
          POOL_MERGE, POOL_RESIDENCY, ADMIT_BATCH, SPEC)
 
 

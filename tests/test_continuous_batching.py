@@ -54,6 +54,20 @@ def test_cross_mode_identity_ragged_budgets(setup, reference, mode):
     assert out == reference
 
 
+@pytest.mark.parametrize("mode", [Mode.HBCEM, Mode.LBIM])
+def test_one_result_pull_per_step(setup, reference, mode):
+    """The NaN guard and token selection read the device once per step:
+    plain decode steps, steps carrying an admission chunk (the one that
+    drains the stream seeds its first token too) and the batch admission
+    alike, with the reference's tokens."""
+    cfg, params = setup
+    eng = Engine(cfg, params, max_len=MAX_LEN, slots=2, mode=mode, chunk=4)
+    assert serve_tokens(eng, PROMPTS, BUDGETS) == reference
+    assert any(e.plan.decode and not e.plan.prefill_chunk for e in eng.events)
+    assert any(e.plan.decode and e.plan.prefill_chunk for e in eng.events)
+    assert [e.result_pulls for e in eng.events] == [1] * len(eng.events)
+
+
 def test_per_request_max_new_stops_slot(setup):
     """No slot decodes past its own budget: kept tokens == decoded slot-steps
     (plus the prefill-seeded first token per request)."""

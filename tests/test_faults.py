@@ -24,6 +24,7 @@ import warnings
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import get_config
@@ -31,6 +32,7 @@ from repro.core import interleave
 from repro.core.pim_modes import Mode
 from repro.models import model as M
 from repro.pimsim import CDPIM, JETSON, LLAMA_1B, replay_events
+from repro.serve import sampling
 from repro.serve.api import (FINISH_CANCELLED, FINISH_FAILED, FINISH_TIMEOUT,
                              TERMINAL_STATES, GenerationRequest,
                              RequestState)
@@ -274,6 +276,40 @@ def test_nan_logits_trip_finite_guard(setup, baseline):
     assert [r.tokens for r in res] == baseline[Mode.HBCEM]
     assert eng.health()["ladder"]["decode_attention"]["nan_trips"] >= 1
     _assert_no_leaks(eng)
+
+
+@pytest.fixture(scope="module")
+def checker(setup):
+    cfg, params = setup
+    return _engine(cfg, params, Mode.LBIM)
+
+
+@pytest.mark.parametrize("nan_guard", [True, False], ids=["guard", "no_guard"])
+@pytest.mark.parametrize("where,trips", [
+    ("active_lane", True), ("free_lane", False),
+    ("chunk_last", True), ("chunk_earlier", False), ("none", False)])
+@pytest.mark.parametrize("bad", [float("nan"), float("inf")], ids=["nan", "inf"])
+def test_check_flags_only_rows_that_become_tokens(checker, where, trips,
+                                                  nan_guard, bad):
+    """The step's check-and-sample program flags a non-finite logit in an
+    active lane or in the admission chunk's last position — the rows that
+    become tokens — and nothing else; with the guard off it never trips."""
+    logits = np.zeros((2, 1, 11), np.float32)   # lane 0 active, lane 1 free
+    pre = np.zeros((1, 3, 11), np.float32)      # a 3-token admission chunk
+    at = {"active_lane": (logits, (0, 0, 4)), "free_lane": (logits, (1, 0, 4)),
+          "chunk_last": (pre, (0, 2, 7)), "chunk_earlier": (pre, (0, 1, 7))}
+    if where in at:
+        arr, idx = at[where]
+        arr[idx] = bad
+    checker.nan_guard = nan_guard
+    try:
+        out = np.asarray(checker._check(
+            jnp.asarray(logits), sampling.Lanes(jnp.asarray([False, True])),
+            jnp.asarray(pre), sampling.Lanes(jnp.zeros((1,), bool))))
+    finally:
+        checker.nan_guard = True
+    assert out.shape == (4,)
+    assert out[-1] == int(not (trips and nan_guard))
 
 
 def test_gemv_faults_degrade_independently_of_attention(setup, baseline):

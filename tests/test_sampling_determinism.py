@@ -135,6 +135,67 @@ def test_request_key_ignores_scheduling_but_not_prompt():
         sampling.request_key(7, [1, 1])).tolist()
 
 
+# ---------------------------------------------- check_and_sample (unit)
+
+
+@pytest.mark.parametrize("positions", [1, 3])
+@pytest.mark.parametrize("temps,ks,ps", [
+    ([0.0, 0.0, 0.0, 0.0], None, None),                   # all-greedy rule
+    ([0.0, 0.8, 1.5, 0.9], None, None),                   # greedy rider
+    ([1.1, 0.6, 0.7, 1.3], [8, 0, 3, 40], [1.0, 0.9, 0.7, 0.5]),
+], ids=["greedy", "mixed", "filtered"])
+def test_check_and_sample_tokens_equal_the_rules(temps, ks, ps, positions):
+    """The one-program path emits exactly what the per-position rules emit:
+    ``greedy_masked`` when every lane is greedy, else ``sample_masked`` on
+    each position's token key (``emitted + j``), with done lanes padded."""
+    lg = jax.random.normal(jax.random.PRNGKey(7), (4, positions, 41))
+    done = jnp.asarray([False, True, False, False])
+    p = _params(4, temps, ks=ks, ps=ps)
+    offs = jnp.asarray([0, 3, 5, 11], jnp.uint32)
+    greedy = all(t == 0 for t in temps)
+    lanes = (sampling.Lanes(done) if greedy else sampling.Lanes(
+        done, temperature=p["temperature"], top_k=p["top_k"],
+        top_p=p["top_p"], keys=p["keys"], offsets=offs))
+    out = np.asarray(sampling.check_and_sample([(lg, lanes)]))
+    assert out.shape == (4 * positions + 1,) and out[-1] == 1
+    got = out[:-1].reshape(4, positions)
+    for j in range(positions):
+        row = lg[:, j:j + 1, :]
+        if greedy:
+            want = sampling.greedy_masked(row, done)
+        else:
+            keys = jax.vmap(jax.random.fold_in)(p["keys"], offs + j)
+            want = sampling.sample_masked(
+                row, done, keys=keys, temperature=p["temperature"],
+                top_k=p["top_k"], top_p=p["top_p"])
+        assert (got[:, j] == np.asarray(want)).all(), j
+    assert (got[1] == 0).all()  # the done lane emits pad
+
+
+@pytest.mark.parametrize("temp", [0.0, 0.9])
+def test_check_and_sample_packs_a_first_token_after_the_lanes(temp):
+    """A prefill row's first token follows the lanes' tokens, drawn as a
+    batch-1 ``sample_masked`` on its request's token key would draw it."""
+    lg = _logits(b=3, v=23, seed=8)
+    pre = _logits(b=1, v=23, seed=9)
+    lanes = sampling.Lanes(jnp.zeros((3,), bool))
+    key = jax.random.PRNGKey(5)
+    first = (sampling.Lanes(jnp.zeros((1,), bool)) if temp == 0 else
+             sampling.Lanes(jnp.zeros((1,), bool),
+                            temperature=jnp.asarray([temp], jnp.float32),
+                            top_k=jnp.zeros((1,), jnp.int32),
+                            top_p=jnp.ones((1,), jnp.float32),
+                            keys=key[None], offsets=jnp.asarray([4], jnp.uint32)))
+    out = np.asarray(sampling.check_and_sample([(lg, lanes), (pre, first)]))
+    assert out.shape == (5,) and out[-1] == 1
+    assert (out[:3] == np.asarray(sampling.greedy(lg))).all()
+    want = sampling.sample_masked(
+        pre, jnp.zeros((1,), bool), keys=sampling.token_key(key, 4)[None],
+        temperature=jnp.asarray([temp], jnp.float32),
+        top_k=jnp.zeros((1,), jnp.int32), top_p=jnp.ones((1,), jnp.float32))
+    assert out[3] == int(np.asarray(want)[0])
+
+
 # -------------------------------------------- engine-level determinism (e2e)
 
 

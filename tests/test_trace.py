@@ -91,7 +91,7 @@ def test_one_dispatch_per_step_named_by_its_event(traced):
     assert steps == list(range(1, len(eng.events)))
     assert any(e.plan.fused for e in eng.events)
     assert {name for _, _, name, _ in spans} >= {
-        trace.SCHEDULE, trace.DISPATCH, trace.GUARD, trace.SAMPLE, trace.EMIT,
+        trace.SCHEDULE, trace.DISPATCH, trace.SAMPLE, trace.EMIT,
         trace.ON_TOKEN, trace.ADMIT_BATCH, *POOL}
 
 
