@@ -16,7 +16,9 @@ persistent `slots`-lane pool, the prefill half is whatever pending request
 is currently being chunk-loaded into a freed slot (typically batch 1). The
 final chunk of a prompt may be shorter than the admission chunk — chunks are
 never padded, so state-carrying families (ssm/hybrid) stream through the
-same path without corruption.
+same path without corruption. Over a fully paged pool the two halves differ
+only in their block tables and fill levels: they write disjoint pages of one
+pair of page arrays, so admission never forks the pool.
 
 Both halves use the same weights — the "two Pbanks each" split is a
 scheduling statement, not a weight copy.
@@ -46,9 +48,22 @@ def fused_step(
     pre_tokens: jax.Array,   # (Bp, C)  next wave's prefill chunk
     cfg: ModelConfig,
 ):
-    """Returns (dec_logits, dec_cache', pre_logits, pre_cache')."""
+    """Returns (dec_logits, dec_cache', pre_logits, pre_cache').
+
+    Over a paged pool both halves write one pair of page arrays: the chunk
+    writes its fresh pages into the arrays the decode half returns (their
+    pages are disjoint), ``dec_cache'`` carries the result, and
+    ``pre_cache'`` only the stream's block table and fill level. No second
+    copy of the pool exists."""
     dec_logits, dec_cache = M.decode_step(params, dec_cache, dec_tokens, cfg)
+    shared = "k_pages" in dec_cache and "block_table" in pre_cache
+    if shared:
+        pre_cache = dict(pre_cache, k_pages=dec_cache["k_pages"],
+                         v_pages=dec_cache["v_pages"])
     pre_logits, pre_cache = M.decode_step(params, pre_cache, pre_tokens, cfg)
+    if shared:
+        dec_cache = dict(dec_cache, k_pages=pre_cache.pop("k_pages"),
+                         v_pages=pre_cache.pop("v_pages"))
     return dec_logits, dec_cache, pre_logits, pre_cache
 
 

@@ -119,19 +119,17 @@ def attention_dense(
     scale = _scale(cfg)
 
     cq = min(cfg.q_chunk, t)
-    if t % cq == 0:
-        n_chunks = t // cq
-    else:
-        n_chunks, cq = 1, t  # ragged tail: fall back to a single chunk
+    n_chunks = -(-t // cq)   # a ragged tail is one shorter last chunk
 
     k_pos_full = jnp.arange(t)
 
     def chunk(i, skip: bool):
-        qs = jax.lax.dynamic_slice_in_dim(qg, i * cq, cq, axis=3)
-        q_pos = i * cq + jnp.arange(cq)
+        n = min(cq, t - i * cq)
+        qs = jax.lax.dynamic_slice_in_dim(qg, i * cq, n, axis=3)
+        q_pos = i * cq + jnp.arange(n)
         if skip and causal:
             # beyond-paper: only the visible KV prefix for this chunk
-            klen = (i + 1) * cq
+            klen = i * cq + n
             ks = k[:, :, :klen, :]
             vs = v[:, :, :klen, :]
             k_pos = k_pos_full[:klen]

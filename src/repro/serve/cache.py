@@ -294,14 +294,11 @@ class _PagesExhausted(Exception):
 
 @dataclass
 class _StagingHandle:
-    """The (single) in-flight admission stream's page residency. ``table``
-    is its logical->physical map; ``fresh`` lists the pages allocated for
-    the stream's OWN writes — the only pages whose content must be copied
-    from the stream's forked arrays into the pool arrays at insert (matched
-    prefix pages are read-only and already live in the pool)."""
+    """The (single) in-flight admission stream's page residency: ``table``
+    is its logical->physical map (matched prefix pages read-only, then the
+    pages its chunks write in the pool arrays)."""
 
     table: np.ndarray
-    fresh: list = field(default_factory=list)
 
 
 class PagedKVState:
@@ -536,7 +533,8 @@ class PagedKVState:
     def begin_staging(self, pages: list[int]) -> dict:
         """Open the admission stream: matched prefix pages enter its block
         table read-only — zero copies, no gather. Returns the stream's
-        batch-1 cache dict (over the POOL arrays; the first step forks)."""
+        batch-1 cache dict over the POOL arrays, into which its chunks write
+        their fresh pages."""
         self.release_staging()  # defensive: a stale handle leaks pages
         table = np.full((self.n_blocks,), -1, np.int64)
         for i, p in enumerate(pages):
@@ -564,9 +562,7 @@ class PagedKVState:
                    self.n_blocks * self.block_size) - 1
         for b in range(off // self.block_size, last // self.block_size + 1):
             if h.table[b] < 0:
-                p = self._alloc_page()
-                h.table[b] = p
-                h.fresh.append(p)
+                h.table[b] = self._alloc_page()
         out = dict(cache)
         out["block_table"] = self._staging_table()
         return out
@@ -586,29 +582,19 @@ class PagedKVState:
 
     def insert(self, src_cache: dict, slot: int, src_slot: int) -> None:
         self._drop_row(slot)
-        if "k_pages" in src_cache:
-            self._consume_staging(src_cache, slot)
+        if "block_table" in src_cache:
+            self._consume_staging(slot)
         else:
             self._pagify_lane(src_cache, slot, src_slot)
 
-    def _consume_staging(self, src_cache: dict, slot: int) -> None:
-        """Merge the drained stream into lane ``slot``: copy its FRESH pages
-        from the stream's forked arrays into the pool arrays (page-granular
-        aligned copies — the stream and the decode pool wrote disjoint pages
-        since the fork), then hand the table row — and its refcounts — to
-        the slot."""
+    def _consume_staging(self, slot: int) -> None:
+        """Hand the drained stream's table row — and its refcounts — to lane
+        ``slot``. Its chunks wrote their fresh pages into the pool arrays
+        themselves, so no page moves."""
         h = self.staging
         if h is None:
             raise EngineStateError(
                 "paged insert from a stream cache with no staging handle")
-        if h.fresh:
-            idx = np.asarray(sorted(h.fresh), np.int64)
-            self._set_pages({
-                "k_pages": self.pages["k_pages"].at[:, idx].set(
-                    src_cache["k_pages"][:, idx]),
-                "v_pages": self.pages["v_pages"].at[:, idx].set(
-                    src_cache["v_pages"][:, idx]),
-            })
         self.block_tables[slot] = h.table
         self.staging = None
 
@@ -923,8 +909,8 @@ class CachePool:
         """Drop lane ``src_slot`` of a prefilled cache into lane ``slot``;
         with ``prompt``, harvest its full blocks into the prefix index.
         For paged pools the source is either the drained admission stream
-        (pages merged, table row transferred) or a contiguous prefill
-        (pagified block by block)."""
+        (its table row transferred; its chunks already wrote the pool's
+        pages) or a contiguous prefill (pagified block by block)."""
         with trace.span(trace.POOL_MERGE):
             kv = self._kv
             if kv is not None:
@@ -1097,13 +1083,51 @@ class CachePool:
         (paged pools; contiguous staging passes through untouched). Called
         by the engine before every chunk step; idempotent under retries."""
         kv = self._kv
-        if kv is None or "k_pages" not in cache:
+        if kv is None or "block_table" not in cache:
             return cache
         with trace.span(trace.POOL_RESIDENCY):
             try:
                 return kv.ensure_staging(cache, n_tokens)
             except _PagesExhausted as e:
                 raise PoolExhausted(str(e), self.occupancy()) from None
+
+    def stream_cache(self, cache: dict, over: Optional[dict] = None) -> dict:
+        """The admission stream's input to a chunk step. Paged pools: its
+        block table and fill level over the page arrays of ``over`` (the
+        step's decode output, when the step decodes) or else the pool's
+        own, so the chunk writes its fresh pages where the pool keeps them
+        and the pool is never forked. Contiguous staging passes through."""
+        kv = self._kv
+        if kv is None or "block_table" not in cache:
+            return cache
+        pages = kv.pages if over is None else over
+        return dict(cache, k_pages=pages["k_pages"], v_pages=pages["v_pages"])
+
+    @staticmethod
+    def fold_stream(new_cache: Optional[dict],
+                    new_scache: dict) -> tuple[Optional[dict], dict]:
+        """After a step whose chunk wrote into the decode output's pages:
+        those pages (now holding both halves' writes) go to the decode
+        cache, and the stream keeps its table and fill level."""
+        if new_cache is None or "k_pages" not in new_scache:
+            return new_cache, new_scache
+        s = dict(new_scache)
+        return dict(new_cache, k_pages=s.pop("k_pages"),
+                    v_pages=s.pop("v_pages")), s
+
+    def commit_stream(self, new_scache: dict) -> dict:
+        """Absorb a clean step's stream output; returns the stream cache to
+        keep. Page arrays it still carries (a chunk step with no decode
+        half) become the pool's pages; the stream keeps its table and fill
+        level only, so no stale copy of the pool outlives the step."""
+        kv = self._kv
+        if kv is None or "block_table" not in new_scache:
+            return new_scache
+        s = dict(new_scache)
+        if "k_pages" in s:
+            kv.commit({"k_pages": s.pop("k_pages"),
+                       "v_pages": s.pop("v_pages")})
+        return s
 
     def release_staging(self) -> None:
         """Abort the in-flight admission stream, releasing its pages

@@ -730,7 +730,8 @@ class Engine:
                                 stream.toks[:, stream.off:stream.off + c])
                             logits, new_cache, pre_logits, new_scache = \
                                 interleave.fused_step(
-                                    dparams, views, feed, stream.cache,
+                                    dparams, views, feed,
+                                    pool.stream_cache(stream.cache),
                                     chunk_toks, cfg_step)
                         else:
                             if plan.decode:
@@ -746,8 +747,12 @@ class Engine:
                                     stream.toks[:, stream.off:stream.off + c])
                                 pre_logits, new_scache = \
                                     interleave.prefill_chunk_step(
-                                        dparams, stream.cache, chunk_toks,
-                                        cfg_step)
+                                        dparams,
+                                        pool.stream_cache(stream.cache,
+                                                          new_cache),
+                                        chunk_toks, cfg_step)
+                                new_cache, new_scache = pool.fold_stream(
+                                    new_cache, new_scache)
                         # the token rule's inputs go up while the step runs:
                         # a decode lane keys on its request's emitted count,
                         # as does the admission stream's last position (its
@@ -887,7 +892,7 @@ class Engine:
                 pool.commit(new_cache)
             if new_scache is not None:
                 self._require(stream is not None, "stream vanished mid-step")
-                stream.cache = new_scache
+                stream.cache = pool.commit_stream(new_scache)
                 stream.off += c
 
             if plan.decode:

@@ -72,3 +72,16 @@ def test_causal_block_skip_matches_full():
     x1 = M.forward(params, batch, cfg.replace(causal_block_skip=True))
     x2 = M.forward(params, batch, cfg.replace(causal_block_skip=False))
     assert float(jnp.max(jnp.abs(x1 - x2))) < 1e-5
+
+
+@pytest.mark.parametrize("skip", [True, False])
+def test_ragged_tail_chunks_match_one_chunk(skip):
+    """A sequence that is not a whole number of query chunks is chunked with
+    a shorter last chunk, and matches attention over one chunk of it all."""
+    cfg = get_config("llama3-8b", smoke=True).replace(
+        dtype="float32", param_dtype="float32", causal_block_skip=skip)
+    params = M.init_params(jax.random.PRNGKey(0), cfg)
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (2, 14), 0, cfg.vocab_size)}
+    x1 = M.forward(params, batch, cfg.replace(q_chunk=4))
+    x2 = M.forward(params, batch, cfg.replace(q_chunk=16))
+    assert float(jnp.max(jnp.abs(x1 - x2))) < 1e-5
