@@ -21,7 +21,10 @@ the cache a typed object instead:
   materialize contiguously: per-slot block tables map logical blocks to
   physical pages, the decode step appends the new token IN PLACE
   (``kv_mapping.append_layer_paged``), and the split-KV flash kernel
-  consumes the same tables through scalar-prefetch index maps.
+  consumes the same tables through scalar-prefetch index maps. The step
+  programs take the page arrays by donation (``views(handover=True)``) and
+  the pool adopts their outputs at dispatch (:meth:`CachePool.adopt`), so
+  one pair of page arrays exists.
 * Pages are **refcounted**: an active lane's table row, the staging stream's
   handle, the prefix index, and any live :class:`LaneFork` each hold one
   reference per page, and a page returns to the free list exactly when its
@@ -73,7 +76,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.core import kv_mapping
+from repro.core import interleave, kv_mapping
 from repro.models import model as M
 from repro.serve import trace
 from repro.serve.errors import (EngineStateError, PoolExhausted,
@@ -629,8 +632,8 @@ class PagedKVState:
                 "block_table": jnp.asarray(eff, jnp.int32)}
 
     def commit(self, new_cache: dict) -> None:
-        self.pages = {"k_pages": new_cache["k_pages"],
-                      "v_pages": new_cache["v_pages"]}
+        """Nothing to take: a step's page arrays were adopted when its
+        program was dispatched (:meth:`CachePool.adopt`)."""
 
     def reset_lanes(self) -> None:
         """Drop every lane row, any staging stream, and any leaked fork; the
@@ -714,8 +717,9 @@ class CachePool:
     One protocol for every family: ``alloc``/``insert``/``retire`` do the
     lane surgery, ``views()`` hands the decode step its cache dict (for
     paged pools: pages + block tables, with active lanes' write blocks
-    paged-in), ``commit()`` takes the step's output back (pinning free
-    lanes' fill to 0 so their dummy decodes never overflow).
+    paged-in), ``adopt()`` takes a dispatched program's page arrays,
+    ``commit()`` the clean step's fill levels (pinning free lanes' fill to
+    0 so their dummy decodes never overflow).
     ``stage_admission`` opens the chunk-prefill stream — on a prefix hit the
     shared pages enter its block table read-only, nothing is gathered or
     copied. The prefix index survives :meth:`reset`, so reuse works across
@@ -779,6 +783,7 @@ class CachePool:
             self._pos = cache["pos"]
         self.slots: list[SlotInfo] = [SlotInfo() for _ in range(self.n_slots)]
         self._copied = 0
+        self._undonated = 0
         self.take_copy_bytes()   # a kept page pool's earlier copies are dropped
 
     def _set_pos(self, pos: jax.Array) -> None:
@@ -793,6 +798,13 @@ class CachePool:
         n, self._copied = self._copied, 0
         for st in self.states:
             n, st.copied = n + st.copied, 0
+        return n
+
+    def take_undonated_bytes(self) -> int:
+        """Bytes of the pool's page arrays that step programs received
+        without donation, and so copied whole into their outputs, since the
+        last call (see :meth:`adopt`)."""
+        n, self._undonated = self._undonated, 0
         return n
 
     def reset(self) -> None:
@@ -939,11 +951,14 @@ class CachePool:
         self._set_pos(self._pos.at[slot].set(0))
         self.slots[slot] = replace(self.slots[slot], state=FREE)
 
-    def views(self, span: int = 1) -> dict:
+    def views(self, span: int = 1, handover: bool = False) -> dict:
         """The decode-step cache dict. Paged pools page-in every active
         lane's write blocks for the next ``span`` appends here (host-side
         residency, idempotent — a retried step re-ensures the same pages).
-        A speculative verify step passes ``span = k + 1``."""
+        A speculative verify step passes ``span = k + 1``. With
+        ``handover`` the dict is an :class:`interleave.Handover`: the step
+        program it is given to takes the page arrays by donation, and
+        :meth:`adopt` takes its output pages back."""
         with trace.span(trace.POOL_RESIDENCY):
             kv = self._kv
             if kv is not None:
@@ -958,12 +973,37 @@ class CachePool:
             for st in self.states:
                 out.update(st.views())
             out["pos"] = self._pos
+            return interleave.Handover(out) if handover else out
+
+    def adopt(self, handover: dict, out: dict) -> dict:
+        """Take the page arrays a dispatched step program left in
+        ``handover`` as the pool's own; returns ``out``, the program's
+        cache, without them.
+
+        Called as soon as a program is dispatched, clean or not: the fill
+        levels stay as they were until :meth:`commit`. A step writes only
+        its lanes' (and the stream's) positions ``[pos, pos + T)``, and in
+        every layer before attention reads them, so a retried attempt that
+        starts from a failed one's pages, at the same fill levels, computes
+        bit-identical results. Arrays the pool handed over that are still
+        alive were not donated: the program copied them whole, and their
+        bytes are counted (:meth:`take_undonated_bytes`)."""
+        kv = self._kv
+        if kv is None:
             return out
+        new = {k: handover[k] for k in interleave.PAGE_KEYS}
+        for k, old in kv.pages.items():
+            if old is not new[k] and not old.is_deleted():
+                self._undonated += old.nbytes
+        kv.pages = new
+        return interleave.without_pages(out)
 
     def commit(self, new_cache: dict) -> None:
         """Absorb a decode step's updated cache. Free lanes decode garbage
         each step; their fill level is pinned back to 0 here so the dummy KV
-        write keeps landing at block 0 (the dummy page) and never overflows."""
+        write keeps landing at block 0 (the dummy page) and never overflows.
+        A paged pool's page arrays are not taken here but at dispatch
+        (:meth:`adopt`)."""
         with trace.span(trace.POOL_COMMIT):
             for st in self.states:
                 st.commit(new_cache)
@@ -1091,43 +1131,17 @@ class CachePool:
             except _PagesExhausted as e:
                 raise PoolExhausted(str(e), self.occupancy()) from None
 
-    def stream_cache(self, cache: dict, over: Optional[dict] = None) -> dict:
-        """The admission stream's input to a chunk step. Paged pools: its
-        block table and fill level over the page arrays of ``over`` (the
-        step's decode output, when the step decodes) or else the pool's
-        own, so the chunk writes its fresh pages where the pool keeps them
-        and the pool is never forked. Contiguous staging passes through."""
+    def stream_cache(self, cache: dict) -> dict:
+        """The admission stream's input to a chunk step. Paged pools: a
+        handover of the pool's page arrays with the stream's block table and
+        fill level, so the chunk writes its fresh pages where the pool keeps
+        them (after this step's decode half, when there is one) and the pool
+        is never forked; :meth:`adopt` takes them back. Contiguous staging
+        passes through."""
         kv = self._kv
         if kv is None or "block_table" not in cache:
             return cache
-        pages = kv.pages if over is None else over
-        return dict(cache, k_pages=pages["k_pages"], v_pages=pages["v_pages"])
-
-    @staticmethod
-    def fold_stream(new_cache: Optional[dict],
-                    new_scache: dict) -> tuple[Optional[dict], dict]:
-        """After a step whose chunk wrote into the decode output's pages:
-        those pages (now holding both halves' writes) go to the decode
-        cache, and the stream keeps its table and fill level."""
-        if new_cache is None or "k_pages" not in new_scache:
-            return new_cache, new_scache
-        s = dict(new_scache)
-        return dict(new_cache, k_pages=s.pop("k_pages"),
-                    v_pages=s.pop("v_pages")), s
-
-    def commit_stream(self, new_scache: dict) -> dict:
-        """Absorb a clean step's stream output; returns the stream cache to
-        keep. Page arrays it still carries (a chunk step with no decode
-        half) become the pool's pages; the stream keeps its table and fill
-        level only, so no stale copy of the pool outlives the step."""
-        kv = self._kv
-        if kv is None or "block_table" not in new_scache:
-            return new_scache
-        s = dict(new_scache)
-        if "k_pages" in s:
-            kv.commit({"k_pages": s.pop("k_pages"),
-                       "v_pages": s.pop("v_pages")})
-        return s
+        return interleave.Handover(cache, **kv.pages)
 
     def release_staging(self) -> None:
         """Abort the in-flight admission stream, releasing its pages

@@ -107,6 +107,9 @@ class ScheduleEvent:
     pool_copy_bytes: int = 0  # bytes written by the pool's eager updates
     #                           from the previous step's end to this one's
     #                           (whole arrays: CachePool.take_copy_bytes)
+    undonated_page_bytes: int = 0  # bytes of page arrays the step's programs
+    #                                received without donation, and so
+    #                                copied whole (take_undonated_bytes)
     # --- host syncs -------------------------------------------------------
     result_pulls: int = 0  # blocking device-to-host reads of the NaN guard
     #                        and token selection (retried attempts included)
@@ -245,6 +248,7 @@ class Engine:
         ev.arrivals = self._take_arrivals()
         ev.queue_depth = self._queue_depth
         ev.pool_copy_bytes = self.pool.take_copy_bytes()
+        ev.undonated_page_bytes = self.pool.take_undonated_bytes()
         self.events.append(ev)
         # an idle event jumps the clock straight to the next arrival; every
         # other event is one engine step plus any injected slow penalty
@@ -597,9 +601,12 @@ class Engine:
                     p = ext_prompt(r)
                     staging, skip = pool.stage_admission(p)
                     self._pending_reuse += skip
+                    # the stream keeps its table and fill level: its chunks
+                    # take the pool's page arrays at each dispatch
                     stream = _Prefill(
                         req=r, toks=np.asarray([p], np.int32),
-                        cache=staging, off=skip, reused=skip)
+                        cache=interleave.without_pages(staging), off=skip,
+                        reused=skip)
 
                 # starvation-aware admission rate: each FREE lane is wasted
                 # decode bandwidth, so the controller lets the processor run a
@@ -672,10 +679,13 @@ class Engine:
                     # (host-side residency; idempotent under ladder retries)
                     stream.cache = pool.staging_step_prep(stream.cache, c)
 
-            # ---- guarded step execution: compute WITHOUT mutating pool or
-            # stream; on a KernelFault (injected or a NaN/Inf trip), demote
-            # the implicated op down the dispatch ladder and retry. Commit only
-            # a clean step's outputs — a retried step never double-appends.
+            # ---- guarded step execution: on a KernelFault (injected or a
+            # NaN/Inf trip), demote the implicated op down the dispatch ladder
+            # and retry. The programs update the pool's page arrays in place
+            # (donated), and the pool adopts their outputs at dispatch; fill
+            # levels and the stream move only at a clean step's commit, so a
+            # retry rewrites the failed attempt's positions before reading
+            # them and never double-appends.
             dparams = self.serving.decode_params
             logits = pre_logits = new_cache = new_scache = None
             attempts, pulls, step_ok = 0, 0, False
@@ -719,7 +729,8 @@ class Engine:
                             span = 1 + max(len(d) for d in drafts.values())
                             forks = {si: pool.fork_lane(si) for si in active}
                             pos_before = {si: forks[si].pos for si in active}
-                    views = pool.views(span=1) if plan.decode else None
+                    views = (pool.views(span=1, handover=True)
+                             if plan.decode else None)
                     with trace.span(trace.DISPATCH, step=len(self.events)):
                         feed = jnp.asarray(cur_tok)[:, None]
                         if plan.fused:
@@ -730,14 +741,15 @@ class Engine:
                                 stream.toks[:, stream.off:stream.off + c])
                             logits, new_cache, pre_logits, new_scache = \
                                 interleave.fused_step(
-                                    dparams, views, feed,
-                                    pool.stream_cache(stream.cache),
+                                    dparams, views, feed, stream.cache,
                                     chunk_toks, cfg_step)
+                            new_cache = pool.adopt(views, new_cache)
                         else:
                             if plan.decode:
                                 logits, new_cache = \
                                     interleave.decode_only_step(
                                         dparams, views, feed, cfg_step)
+                                new_cache = pool.adopt(views, new_cache)
                             if plan.prefill_chunk:
                                 self._require(
                                     stream is not None,
@@ -745,14 +757,12 @@ class Engine:
                                     "admission stream in flight")
                                 chunk_toks = jnp.asarray(
                                     stream.toks[:, stream.off:stream.off + c])
+                                # over the decode half's output pages
+                                scache = pool.stream_cache(stream.cache)
                                 pre_logits, new_scache = \
                                     interleave.prefill_chunk_step(
-                                        dparams,
-                                        pool.stream_cache(stream.cache,
-                                                          new_cache),
-                                        chunk_toks, cfg_step)
-                                new_cache, new_scache = pool.fold_stream(
-                                    new_cache, new_scache)
+                                        dparams, scache, chunk_toks, cfg_step)
+                                new_scache = pool.adopt(scache, new_scache)
                         # the token rule's inputs go up while the step runs:
                         # a decode lane keys on its request's emitted count,
                         # as does the admission stream's last position (its
@@ -769,7 +779,6 @@ class Engine:
                         if span == 1:
                             out = self._check(logits, lanes, pre_logits,
                                               first)
-                    # the step's input pages must not outlive its commit
                     views = None
                     if plan.spec and span > 1:
                         # Verify scores every span position through the SAME
@@ -792,10 +801,11 @@ class Engine:
                                 for si, d in drafts.items():
                                     if j - 1 < len(d):
                                         feed_j[si, 0] = d[j - 1]
+                                views = pool.views(span=1, handover=True)
                                 lg_j, nc_j = interleave.decode_only_step(
-                                    dparams, pool.views(span=1),
-                                    jnp.asarray(feed_j), cfg_step)
-                                pool.commit(nc_j)
+                                    dparams, views, jnp.asarray(feed_j),
+                                    cfg_step)
+                                pool.commit(pool.adopt(views, nc_j))
                                 vlogits.append(lg_j)
                             logits = jnp.concatenate(
                                 [jnp.asarray(lg) for lg in vlogits], axis=1)
@@ -892,7 +902,7 @@ class Engine:
                 pool.commit(new_cache)
             if new_scache is not None:
                 self._require(stream is not None, "stream vanished mid-step")
-                stream.cache = pool.commit_stream(new_scache)
+                stream.cache = new_scache
                 stream.off += c
 
             if plan.decode:
@@ -942,7 +952,6 @@ class Engine:
                 H["failures"] += 1
         self.last_requests = reqs       # SLO telemetry (schedule_report)
         del self._reqs, self._eos, self._base_keys
-        self.last_cache = pool.views()  # introspection / tests
         self.last_results = results     # latency telemetry (schedule_report)
         return results
 

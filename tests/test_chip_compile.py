@@ -15,8 +15,11 @@ and 64 tokens; and InternLM2-1.8B's, the language model of InternVL2-2B
 2 and a ``(1, 1, 2, 128)`` query block), at 4 slots, max_len 4608 and pages
 of 64, as its long-context cell serves them. One more compiles the batch
 prefill's layer scan at InternLM2 widths and bounds its scratch memory.
+The last tests compile the engine's step programs whole, at both cells' widths
+and page pools, and check that they update the page arrays in place.
 """
 import os
+import re
 from dataclasses import dataclass
 
 import jax
@@ -154,3 +157,74 @@ def test_long_prompt_batch_prefill_fits_beside_the_pool(topo):
         lambda p, h: M._scan_layers(p, h, cfg, collect_kv=True)
     ).lower(params, x).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+
+
+# The benchmark cells' pools: 4 lanes, the admission stream and one lane of
+# prefix store, of max_len / 64 pages each, plus the dummy page.
+CELLS = {"yi9b-16l": ("yi-9b", 16, 2560), "internvl2-2b": ("internvl2-2b", 24, 4608)}
+
+
+@pytest.mark.parametrize("program,batch,tokens", [
+    ("_decode_only_step", SLOTS, 1),
+    ("_prefill_chunk_step", 1, 64),
+    ("_fused_step", SLOTS, 64),
+])
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_step_programs_update_pages_in_place(topo, cell, program, batch,
+                                             tokens):
+    """The engine's step programs, W8A8 decode weights and all, alias both
+    donated page arrays to their outputs. The decode and chunk programs
+    never copy a whole page array; without donation they copy both
+    (``copy.81``/``.82`` of the long-context cell's trace), which shows the
+    check can see such a copy. The fused program moves the whole pool into
+    the decode kernel's layout and back, with or without donation: those
+    relayouts are its only whole-array copies."""
+    from repro.configs import get_config
+    from repro.core import interleave, quant
+    from repro.models import model as M
+    from repro.serve.serving_model import _prefill_only
+
+    name, layers, max_len = CELLS[cell]
+    cfg = get_config(name).replace(n_layers=layers, quantized_decode=True,
+                                   attn_backend="pallas")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def abstract(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    params = jax.tree.map(abstract, jax.eval_shape(
+        lambda p: quant.prepare_decode_params(p, exclude=_prefill_only),
+        M.param_specs(cfg)))
+    nb = max_len // 64
+    n_pages = (SLOTS + 2) * nb + 1
+    kd = M.kv_cache_dtype(cfg)
+    pages = {
+        "k_pages": jax.ShapeDtypeStruct(
+            (layers, n_pages, cfg.n_kv_heads, HD, 64), kd, sharding=one_chip),
+        "v_pages": jax.ShapeDtypeStruct(
+            (layers, n_pages, cfg.n_kv_heads, 64, HD), kd, sharding=one_chip)}
+
+    def lanes(b):
+        return {"block_table": abstract(jnp.zeros((b, nb), jnp.int32)),
+                "pos": abstract(jnp.zeros((b,), jnp.int32))}
+
+    def toks(b, t):
+        return abstract(jnp.zeros((b, t), jnp.int32))
+    if program == "_fused_step":
+        args = (lanes(batch), toks(batch, 1), lanes(1), toks(1, tokens))
+    else:
+        args = (lanes(batch), toks(batch, tokens))
+    step = getattr(interleave, program)
+    whole = re.compile(rf"= \w+\[{layers},{n_pages},[^=\n]* copy\(")
+
+    donated = step.lower(params, pages, *args, cfg).compile()
+    page_bytes = sum(p.size * p.dtype.itemsize for p in pages.values())
+    assert donated.memory_analysis().alias_size_in_bytes >= page_bytes
+    kept = jax.jit(step.__wrapped__, static_argnames=("cfg",)).lower(
+        params, pages, *args, cfg).compile()
+    assert kept.memory_analysis().alias_size_in_bytes == 0
+    copies = len(whole.findall(donated.as_text()))
+    if program == "_fused_step":
+        assert copies == len(whole.findall(kept.as_text()))
+    else:
+        assert copies == 0
+        assert len(whole.findall(kept.as_text())) >= 2

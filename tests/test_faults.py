@@ -278,6 +278,50 @@ def test_nan_logits_trip_finite_guard(setup, baseline):
     _assert_no_leaks(eng)
 
 
+@pytest.mark.parametrize("mode,spec", [(Mode.LBIM, False), (Mode.HBCEM, True),
+                                       (Mode.LBIM, True)],
+                         ids=["lbim", "hbcem-spec", "lbim-spec"])
+def test_nan_trip_after_dispatch_retries_from_donated_pages(
+        setup, spec_sm, baseline, mode, spec):
+    """The NaN guard trips after the step's programs took the pool's pages
+    by donation: the retry starts from the failed attempt's output pages at
+    the step's fill levels, and tokens equal the fault-free run's."""
+    cfg, params = setup
+    plan = FaultPlan(faults=[Fault("nan_logits", 2), Fault("nan_logits", 3)])
+    eng = (_spec_engine(spec_sm, mode, fault_plan=plan) if spec
+           else _engine(cfg, params, mode, fault_plan=plan))
+    with pytest.warns(RuntimeWarning):
+        res = eng.serve(_reqs())
+    assert [r.tokens for r in res] == baseline[mode]
+    assert plan.fired() == 2
+    assert sum(e.attempts - 1 for e in eng.events) == 2
+    assert [e.undonated_page_bytes for e in eng.events] == [0] * len(eng.events)
+    _assert_no_leaks(eng)
+
+
+@pytest.mark.parametrize("mode", [Mode.HBCEM, Mode.LBIM])
+def test_exhausted_step_fails_participants_then_serves_the_queue(
+        setup, baseline, mode):
+    """A step whose every attempt trips the NaN guard fails its lanes and
+    its admission stream. The pool keeps the last attempt's pages, which
+    the queued requests are then served on, token for token as in the
+    fault-free run."""
+    cfg, params = setup
+    plan = FaultPlan(faults=[Fault("nan_logits", 2), Fault("nan_logits", 2)])
+    eng = _engine(cfg, params, mode, fault_plan=plan, max_step_attempts=2)
+    with pytest.warns(RuntimeWarning):
+        res = eng.serve(_reqs())
+    failed = [i for i, r in enumerate(res) if r.state is RequestState.FAILED]
+    done = [i for i, r in enumerate(res) if r.state is RequestState.FINISHED]
+    assert failed and done and min(failed) < max(done)
+    assert max(done) > max(failed)   # admitted after the failed step
+    for i in done:
+        assert res[i].tokens == baseline[mode][i]
+    for i in failed:
+        assert res[i].tokens == baseline[mode][i][:len(res[i].tokens)]
+    _assert_no_leaks(eng)
+
+
 @pytest.fixture(scope="module")
 def checker(setup):
     cfg, params = setup
